@@ -25,6 +25,4 @@ reference: mgugino-upstream-stage/kubernetes) around a TPU-first compute model:
   plugin/pkg/scheduler/core/extender.go:40).
 """
 
-from kubernetes_tpu import compat as _compat  # noqa: F401  (asyncio.timeout on 3.10)
-
 __version__ = "0.1.0"

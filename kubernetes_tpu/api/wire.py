@@ -11,13 +11,15 @@ the Unknown envelope as JSON bytes (the runtime.RawExtension escape hatch),
 so every payload can negotiate the binary content type.
 
 Generated code is built from wire.proto with the system protoc on first
-import (cached in _wiregen/, keyed by source mtime) and served by the upb C
-runtime. If protoc or the protobuf runtime is missing, `available()` is
+import (cached in _wiregen/, keyed by a hash of wire.proto and the protoc
+command, so a module generated from other sources is rebuilt) and served
+by the upb C runtime. If protoc or the protobuf runtime is missing, `available()` is
 False and callers stay on JSON — negotiation degrades, nothing breaks.
 """
 
 from __future__ import annotations
 
+import hashlib
 import json
 import logging
 import os
@@ -37,9 +39,18 @@ def _load() -> None:
     src = os.path.join(here, "wire.proto")
     gen_dir = os.path.join(here, "_wiregen")
     gen = os.path.join(gen_dir, "wire_pb2.py")
+    stamp = os.path.join(gen_dir, "wire_pb2.key")
+    cmd = ["protoc", f"-I{here}"]
     try:
-        if (not os.path.exists(gen)
-                or os.path.getmtime(gen) < os.path.getmtime(src)):
+        with open(src, "rb") as f:
+            key = hashlib.sha256(
+                f.read() + "\0".join(cmd).encode()).hexdigest()
+        try:
+            with open(stamp, encoding="utf-8") as f:
+                fresh = f.read() == key and os.path.exists(gen)
+        except FileNotFoundError:
+            fresh = False
+        if not fresh:
             os.makedirs(gen_dir, exist_ok=True)
             init = os.path.join(gen_dir, "__init__.py")
             if not os.path.exists(init):
@@ -50,10 +61,13 @@ def _load() -> None:
             # silently degrade to JSON while peers speak protobuf)
             import tempfile
             with tempfile.TemporaryDirectory(dir=here) as tmp:
-                subprocess.run(
-                    ["protoc", f"-I{here}", f"--python_out={tmp}", src],
-                    check=True, capture_output=True, timeout=60)
+                subprocess.run([*cmd, f"--python_out={tmp}", src],
+                               check=True, capture_output=True, timeout=60)
                 os.replace(os.path.join(tmp, "wire_pb2.py"), gen)
+                with open(os.path.join(tmp, "key"), "w",
+                          encoding="utf-8") as f:
+                    f.write(key)
+                os.replace(os.path.join(tmp, "key"), stamp)
         from kubernetes_tpu.api._wiregen import wire_pb2
         _pb = wire_pb2
     except (OSError, subprocess.SubprocessError, ImportError) as e:

@@ -951,6 +951,10 @@ def spawn_worker(spec: WorkerSpec):
 
 def worker_main(spec: WorkerSpec) -> None:
     """Module-level spawn target of one apiserver worker process."""
+    # a worker serves the API only: should anything it imports reach for
+    # JAX, it gets the CPU — the accelerator belongs to the process that
+    # opened it first (one process per chip)
+    os.environ["JAX_PLATFORMS"] = "cpu"
     pin_to_core(spec.worker_id)
     try:
         asyncio.run(_worker_serve(spec))

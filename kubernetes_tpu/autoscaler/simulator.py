@@ -64,7 +64,9 @@ class ScaleSimulator:
                  policy: Policy = DEFAULT_POLICY, volume_ctx=None,
                  mesh=None):
         from kubernetes_tpu.models.policy import build_policy_rows
+        from kubernetes_tpu.utils.compilation_cache import enable
 
+        enable()  # persistent XLA cache before this plane's first compile
         # probe fleets are small: default capacities sized for control-plane
         # what-ifs, not 50k-node scheduling batches (callers override).
         # mesh: run probe solves node-sharded like the scheduler's own

@@ -62,6 +62,9 @@ class ExtenderService:
     def __init__(self, caps: Capacities | None = None,
                  policy: Policy = DEFAULT_POLICY, statedb: StateDB | None = None,
                  store=None, solversvc=None, solversvc_buckets: tuple = ()):
+        from kubernetes_tpu.utils.compilation_cache import enable
+
+        enable()  # persistent XLA cache before the warmup compile
         self.caps = caps or Capacities()
         self.policy = policy.with_env_overrides()
         self.statedb = statedb

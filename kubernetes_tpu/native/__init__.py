@@ -7,21 +7,29 @@ ledger scatter-add behind batch commit (state/statedb.py commit_batch).
 
 Build strategy: compile each .c with the system C compiler into the
 package's `_build/` directory the first time it is imported (a few ms,
-cached thereafter, keyed by source mtime) and bind it with ctypes — the
-image ships g++/cc but not pybind11. Any failure (no compiler, read-only
-filesystem) degrades silently to the pure-Python/numpy implementations;
-callers check the function for None.
+cached thereafter) and bind it with ctypes — the image ships g++/cc but
+not pybind11. Each library's file name carries a hash of its source and
+compiler command, so a `.so` left over from other sources (a copied
+working tree keeps ignored directories) is never loaded. Any failure (no
+compiler, read-only filesystem) degrades to the pure-Python/numpy
+implementations; callers check the function for None (`active()` lists
+what loaded).
 """
 
 from __future__ import annotations
 
+import contextlib
 import ctypes
+import glob
+import hashlib
 import logging
 import os
 import subprocess
 import tempfile
 
 log = logging.getLogger(__name__)
+
+_HERE = os.path.dirname(os.path.abspath(__file__))  # sources; _build/ below
 
 fnv1a64 = None          # (bytes) -> int, or None when unavailable
 lanes_batch = None      # (list[bytes]) -> (np.uint32[n], np.uint32[n])
@@ -33,29 +41,37 @@ bulk_bind = None        # (bucket, bindings, rv_base, WatchEvent, NotFound,
 def _build_lib(src_name: str, stem: str | None = None,
                extra_flags: tuple[str, ...] = (),
                loader=ctypes.CDLL) -> ctypes.CDLL | None:
-    """Compile `src_name` (beside this file) into _build/ if stale and load
-    it. Build via a temp file + rename so concurrent importers can race.
+    """Compile `src_name` (beside this file) into _build/ unless a build of
+    the same source and command exists, and load it. Build via a temp file
+    + rename so concurrent importers can race.
     `stem` names the output .so (one source can build several variants,
     e.g. commitops with/without the CPython API); `loader` picks the ctypes
     binding class (PyDLL for functions that call the Python C-API and must
     hold the GIL). Returns None on any failure (callers degrade to pure
     Python)."""
-    src = os.path.join(os.path.dirname(__file__), src_name)
-    build_dir = os.path.join(os.path.dirname(__file__), "_build")
+    src = os.path.join(_HERE, src_name)
+    build_dir = os.path.join(_HERE, "_build")
     if stem is None:
         stem = os.path.splitext(src_name)[0]
-    lib_path = os.path.join(build_dir, f"lib{stem}.so")
+    cmd = ["cc", "-O2", "-shared", "-fPIC", *extra_flags]
     try:
-        if (not os.path.exists(lib_path)
-                or os.path.getmtime(lib_path) < os.path.getmtime(src)):
+        with open(src, "rb") as f:
+            key = hashlib.sha256(
+                f.read() + "\0".join(cmd).encode()).hexdigest()[:16]
+        lib_path = os.path.join(build_dir, f"lib{stem}-{key}.so")
+        if not os.path.exists(lib_path):
             os.makedirs(build_dir, exist_ok=True)
             fd, tmp = tempfile.mkstemp(dir=build_dir, suffix=".so")
             os.close(fd)
-            subprocess.run(
-                ["cc", "-O2", "-shared", "-fPIC", *extra_flags,
-                 "-o", tmp, src],
-                check=True, capture_output=True, timeout=60)
+            subprocess.run([*cmd, "-o", tmp, src],
+                           check=True, capture_output=True, timeout=60)
             os.replace(tmp, lib_path)
+            for stale in glob.glob(os.path.join(build_dir,
+                                                f"lib{stem}-*.so")):
+                if stale != lib_path:
+                    # a concurrent importer may have removed it first
+                    with contextlib.suppress(FileNotFoundError):
+                        os.remove(stale)
         return loader(lib_path)
     except (OSError, subprocess.SubprocessError) as e:
         log.debug("native %s unavailable (%s); using pure Python",
@@ -172,6 +188,13 @@ def _bind_bindops():
         return
 
     bulk_bind = lib.ktpu_bulk_bind
+
+
+def active() -> dict[str, bool]:
+    """Which native kernels loaded (False: the pure-Python fallback)."""
+    return {"fnv": fnv1a64 is not None,
+            "commitops": scatter_add_cols is not None,
+            "bulk_bind": bulk_bind is not None}
 
 
 _bind_fnv()

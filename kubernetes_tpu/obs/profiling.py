@@ -364,12 +364,17 @@ class DeviceMemoryMonitor:
         """Refresh the gauges (called at scrape time) and return the
         snapshot: backend stats per device where supported, StateDB
         blob accounting always."""
-        devices = []
-        try:
-            import jax
-            devices = list(jax.devices())
-        except Exception:
-            jax = None
+        # devices come from the arrays this process already holds, never
+        # from jax.devices(): a scrape must not open a backend (and with
+        # it the chip) in a process that has not
+        devices: dict = {}
+        jax = sys.modules.get("jax")
+        if jax is not None:
+            for db in statedbs:
+                for leaf in jax.tree_util.tree_leaves(
+                        getattr(db, "_device", None)):
+                    if isinstance(leaf, jax.Array):
+                        devices.update(dict.fromkeys(leaf.devices()))
         supported = False
         per_device: dict[str, dict] = {}
         for dev in devices:

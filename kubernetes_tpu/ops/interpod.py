@@ -26,6 +26,11 @@ using the virtual composite (zone, region) slot (layout.TOPO_ZONE_REGION).
 
 All counts flow through the solver scan so earlier in-batch assignments are
 visible to later pods, matching the serial scheduleOne semantics.
+
+Exactness: the counts are integers carried in f32, exact to 2**24. A dot at
+DEFAULT precision may round its f32 operands to bf16 on the TPU's MXU,
+which holds integers exactly only to 256, so every dot with a count operand
+runs at `EXACT` (HIGHEST) precision; one-hot x one-hot dots stay at DEFAULT.
 """
 
 from __future__ import annotations
@@ -34,7 +39,7 @@ import jax
 import jax.numpy as jnp
 from flax import struct
 
-from kubernetes_tpu.ops.priorities import FLOOR_EPS
+from kubernetes_tpu.ops.priorities import EXACT, FLOOR_EPS
 from kubernetes_tpu.state.cluster_state import ClusterState
 from kubernetes_tpu.state.layout import (
     MAX_PRIORITY,
@@ -69,7 +74,7 @@ def domain_aggregates(topology: jnp.ndarray, counts: jnp.ndarray,
     """f32[K, D, U]: per-domain sums of per-node counts. one_hot maps the
     -1 (no label) sentinel to an all-zero row, excluding those nodes."""
     onehot = jax.nn.one_hot(topology, domain_universe, axis=-1)  # [N, K, D]
-    return jnp.einsum("nkd,nu->kdu", onehot, counts)
+    return jnp.einsum("nkd,nu->kdu", onehot, counts, precision=EXACT)
 
 
 def topology_onehot(topology: jnp.ndarray, domain_universe: int) -> jnp.ndarray:
@@ -110,7 +115,8 @@ def _slot_counts(topo_onehot: jnp.ndarray, node_counts: jnp.ndarray,
     automatically). K separate [N,D]@[D,U] matmuls at U≈32 ran at ~25%
     lane efficiency each and were the measured device wall of the interpod
     config (PERF.md r4); the batched einsum tiles the K axis together."""
-    out = jnp.einsum("knd,kdu->knu", topo_onehot, dom_counts)
+    out = jnp.einsum("knd,kdu->knu", topo_onehot, dom_counts,
+                     precision=EXACT)
     return out.at[0].set(node_counts)
 
 

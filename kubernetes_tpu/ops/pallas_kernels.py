@@ -8,7 +8,10 @@ fuses behind them, so the (P, N) intermediates that the composed XLA
 kernels materialize in HBM (selector counts, taint violations, per-check
 masks) never leave the chip. Mirrors ops/predicates.py semantics exactly
 (predicates.go:686, :1241, :1306 and the lister's unschedulable filter);
-parity is pinned against the XLA path in tests (interpret mode off-TPU).
+parity is pinned against the XLA path in tests. The kernel always
+compiles for the TPU; a test that runs it elsewhere asks for Pallas's TPU
+interpret mode itself (`pltpu.force_tpu_interpret_mode()`), the program
+never picks it.
 
 Opt-in: the solver uses it when KTPU_PALLAS=1 and the policy's static set
 matches what the kernel fuses (solver._use_fused_static). Node-affinity
@@ -18,12 +21,9 @@ the fused two-matmul shape doesn't cover.
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from kubernetes_tpu.state.layout import Condition
 
@@ -74,10 +74,9 @@ def _kernel(sel_onehot, sel_count, untol, best_effort, pod_lo, pod_hi,
     out[:] = ok.astype(jnp.float32)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@jax.jit
 def fused_static_mask(state, sel_onehot, sel_count, untol, best_effort,
-                      node_name_lo, node_name_hi, *,
-                      interpret: bool = False) -> jnp.ndarray:
+                      node_name_lo, node_name_hi) -> jnp.ndarray:
     """bool[P, N]: valid & schedulable & conditions & selector & taints &
     host-name for every (pod, node) pair.
 
@@ -115,7 +114,6 @@ def fused_static_mask(state, sel_onehot, sel_count, untol, best_effort,
             spec((tile_n, 1), lambda i, j: (j, 0)),
         ],
         out_specs=spec((tile_p, tile_n), lambda i, j: (i, j)),
-        interpret=interpret,
     )(
         sel_onehot,
         sel_count.reshape(p, 1),

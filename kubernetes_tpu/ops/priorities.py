@@ -22,6 +22,7 @@ vector arithmetic.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from kubernetes_tpu.ops.predicates import count_untolerated_prefer_taints
@@ -35,6 +36,11 @@ from kubernetes_tpu.state.pod_batch import PodBatch
 # quotient granularity 10/capacity for any realistic node size) restores exact
 # parity on representable inputs.
 FLOOR_EPS = 1e-6
+
+# Precision of every dot whose operand carries counts or sizes rather than
+# 0/1 memberships: DEFAULT may round f32 operands to bf16 on the TPU's MXU
+# (integers exact only to 256); HIGHEST keeps the full f32 operand.
+EXACT = jax.lax.Precision.HIGHEST
 
 
 def _unused_score(requested: jnp.ndarray, capacity: jnp.ndarray) -> jnp.ndarray:
@@ -164,7 +170,7 @@ def image_locality(state: ClusterState, pod: PodBatch) -> jnp.ndarray:
     """ImageLocalityPriorityMap (image_locality.go:32): bucket the summed
     bytes of the pod's images already present on the node into [0, 10]. One
     matvec: sums = img_size[N, UI] @ img_onehot[UI]."""
-    sums = state.img_size @ pod.img_onehot
+    sums = jnp.dot(state.img_size, pod.img_onehot, precision=EXACT)
     mid = jnp.floor(MAX_PRIORITY * (sums - MIN_IMG_SIZE)
                     / (MAX_IMG_SIZE - MIN_IMG_SIZE) + FLOOR_EPS) + 1.0
     return jnp.where(sums < MIN_IMG_SIZE, 0.0,

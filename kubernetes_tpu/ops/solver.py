@@ -678,8 +678,7 @@ def schedule_batch(
             .astype(jnp.float32))(batch)
         fused = fused_static_mask(
             state, batch.sel_onehot, batch.sel_count, untol,
-            batch.best_effort, batch.node_name_lo, batch.node_name_hi,
-            interpret=jax.default_backend() != "tpu")
+            batch.best_effort, batch.node_name_lo, batch.node_name_hi)
         static_mask = fused & jax.vmap(
             lambda p: _static_rest(state, p, policy, base_mask))(batch)
     else:

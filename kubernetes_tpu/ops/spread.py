@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from kubernetes_tpu.ops.interpod import AffinityLedger
-from kubernetes_tpu.ops.priorities import FLOOR_EPS
+from kubernetes_tpu.ops.priorities import EXACT, FLOOR_EPS
 from kubernetes_tpu.state.cluster_state import ClusterState
 from kubernetes_tpu.state.layout import MAX_PRIORITY, TOPO_SPREAD_ZONE
 
@@ -42,8 +42,8 @@ def selector_spread(state: ClusterState, spread_q, ledger: AffinityLedger,
     has_zone = dom >= 0
     onehot = (jax.nn.one_hot(dom, domain_universe)        # [N, D], -1 -> 0row
               if topo_onehot is None else topo_onehot[TOPO_SPREAD_ZONE])
-    zc = onehot.T @ masked                                # [D] per-zone counts
-    zc_node = onehot @ zc                                 # [N]
+    zc = jnp.dot(onehot.T, masked, precision=EXACT)       # [D] per-zone counts
+    zc_node = jnp.dot(onehot, zc, precision=EXACT)        # [N]
     have_zones = jnp.any(feasible & has_zone)
     max_zone = jnp.max(zc)
 
@@ -80,8 +80,8 @@ def service_anti_affinity(state: ClusterState, svcanti_q, total,
     contrib = jnp.where(feasible & labeled, counts, 0.0)
     onehot = (jax.nn.one_hot(dom, domain_universe)
               if topo_onehot is None else topo_onehot[slot])
-    per_dom = onehot.T @ contrib
-    dom_count = onehot @ per_dom                          # [N]
+    per_dom = jnp.dot(onehot.T, contrib, precision=EXACT)
+    dom_count = jnp.dot(onehot, per_dom, precision=EXACT)  # [N]
     score = jnp.where(
         total > 0,
         jnp.trunc(MAX_PRIORITY * (total - dom_count)

@@ -152,6 +152,9 @@ def make_sharded_scheduler(mesh: Mesh, policy: Policy = DEFAULT_POLICY,
         def packed_fn(state, fblob, iblob, rr, victims=None):
             return jfn(state, fblob, iblob, rr, victims)
 
+        # the jit surface (AOT compiles, HLO pins) stays reachable
+        packed_fn.lower = (lambda state, fblob, iblob, rr, victims=None:
+                           jfn.lower(state, fblob, iblob, rr, victims))
         return packed_fn
     return jax.jit(
         lambda state, batch, rr: schedule_batch(state, batch, rr, policy,

@@ -213,6 +213,9 @@ class SolverService:
                  clock: Clock = SYSTEM_CLOCK, window_s: float = 0.005,
                  flow: FlowController | None = None, total_seats: int = 32,
                  queue_wait_s: float = 2.0, min_bucket: int = 4):
+        from kubernetes_tpu.utils.compilation_cache import enable
+
+        enable()  # persistent XLA cache before this plane's first compile
         self.caps = caps or Capacities(num_nodes=256, batch_pods=64)
         self.policy = policy.with_env_overrides()
         self.clock = clock

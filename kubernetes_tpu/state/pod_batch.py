@@ -175,8 +175,8 @@ def empty_batch(caps: Capacities) -> PodBatch:
 def _batch_layout(caps: Capacities):
     """Column layout for blob transport: field -> (blob, offset, width,
     trailing_shape, dtype). Uploading a batch as ~45 small arrays pays ~45
-    per-transfer latencies on remote-device transports; two contiguous blobs
-    (one f32, one i32 that also carries u32 bitcast and bools) pay two."""
+    per-transfer latencies; two contiguous blobs (one f32, one i32 that
+    also carries u32 bitcast and bools) pay two."""
     proto = empty_batch(caps)
     layout = {}
     offsets = {"f": 0, "i": 0}

@@ -546,7 +546,7 @@ class StateDB:
             from kubernetes_tpu.parallel.mesh import shard_state
             return shard_state(state, self.mesh)
         # ONE batched transfer for the whole pytree — per-leaf puts pay a
-        # per-call round trip each on remote-device transports
+        # per-call latency each
         return jax.device_put(host)
 
     def _put_arr(self, arr: np.ndarray):

@@ -205,3 +205,28 @@ def test_scheduler_e2e_parity_native_vs_fallback():
     assert native == fallback
     assert len(native[0]) == 24 and all(native[0].values())
     assert native[2] == 24
+
+
+def test_native_build_keyed_by_source_and_flags(tmp_path, monkeypatch):
+    """A library is rebuilt whenever its source or compiler command
+    differs from what built the cached .so (a copied tree keeps ignored
+    build outputs), and the superseded build is removed."""
+    import glob
+
+    from kubernetes_tpu import native
+
+    src = os.path.join(native._HERE, "fnv.c")
+    build = tmp_path / "_build"
+    monkeypatch.setattr(native, "_HERE", str(tmp_path))
+    (tmp_path / "fnv.c").write_bytes(open(src, "rb").read())
+
+    assert native._build_lib("fnv.c") is not None
+    first = glob.glob(str(build / "libfnv-*.so"))
+    assert len(first) == 1
+    assert native._build_lib("fnv.c", extra_flags=("-DKTPU_X=1",))
+    second = glob.glob(str(build / "libfnv-*.so"))
+    assert len(second) == 1 and second != first
+    (tmp_path / "fnv.c").write_bytes(open(src, "rb").read() + b"\n")
+    assert native._build_lib("fnv.c", extra_flags=("-DKTPU_X=1",))
+    third = glob.glob(str(build / "libfnv-*.so"))
+    assert len(third) == 1 and third != second

@@ -1,11 +1,14 @@
 """Pallas fused static-mask kernel: bit-parity with the composed XLA path
-(interpret mode off-TPU) and end-to-end solver parity under KTPU_PALLAS=1."""
+and end-to-end solver parity under KTPU_PALLAS=1. The program always
+compiles the kernel for the TPU; off the chip these tests ask for Pallas's
+TPU interpret mode themselves (the `tpu_interpret` fixture)."""
 
 import os
 
 import jax
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 from kubernetes_tpu.models.policy import DEFAULT_POLICY
 from kubernetes_tpu.ops import predicates as preds
@@ -15,6 +18,17 @@ from kubernetes_tpu.state import Capacities, encode_cluster
 from tests.test_solver import mk_node, mk_pod
 
 CAPS = Capacities(num_nodes=128, batch_pods=16)
+
+
+@pytest.fixture(autouse=True)
+def tpu_interpret():
+    """Run every kernel traced in a test under TPU interpret mode when no
+    TPU is attached (on the chip the compiled kernel runs as-is)."""
+    if jax.default_backend() == "tpu":
+        yield
+        return
+    with pltpu.force_tpu_interpret_mode():
+        yield
 
 
 def fixture():
@@ -68,8 +82,7 @@ def test_fused_mask_matches_composed_xla():
                      .astype(jnp.float32))(batch)
     fused = fused_static_mask(
         state, batch.sel_onehot, batch.sel_count, untol,
-        batch.best_effort, batch.node_name_lo, batch.node_name_hi,
-        interpret=jax.default_backend() != "tpu")
+        batch.best_effort, batch.node_name_lo, batch.node_name_hi)
 
     want = jax.vmap(lambda p: (
         state.valid

@@ -309,6 +309,25 @@ def test_device_memory_cpu_fallback_statedb_accounting():
     assert "device_memory_bytes_limit{" not in r.render()
 
 
+def test_device_memory_scrape_opens_no_backend():
+    """A scrape in a process that holds no device arrays (an apiserver
+    worker, say) must not initialize a JAX backend: on a chip machine
+    that would open the chip another process owns."""
+    code = (
+        "import jax\n"
+        "from jax._src import xla_bridge\n"
+        "from kubernetes_tpu.obs.metrics import Registry\n"
+        "from kubernetes_tpu.obs.profiling import DeviceMemoryMonitor\n"
+        "snap = DeviceMemoryMonitor(registry=Registry()).collect()\n"
+        "assert snap['devices'] == {}, snap\n"
+        "assert not xla_bridge.backends_are_initialized()\n")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=str(Path(__file__).resolve().parent.parent))
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
+
+
 def test_statedb_flush_and_readback_transfer_counters():
     """flush() charges statedb_flush_bytes_total; record_readback
     charges device_readback_bytes_total."""

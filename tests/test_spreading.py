@@ -410,3 +410,106 @@ class TestPolicyJson:
         assert policy.service_affinity_labels() == ("region",)
         rt = Policy.from_json(policy.to_json())
         assert rt == policy
+
+
+class TestExactCounts:
+    """Counts past bf16's exact-integer range (256). A dot at DEFAULT
+    precision may round f32 operands to bf16 on the TPU's MXU; CPU
+    arithmetic is exact either way, so the exact form (HIGHEST) is pinned
+    in the lowered program text, and the semantics by serial parity."""
+
+    # distinct universe widths, so each dot's operand shapes name its site
+    CAPS = Capacities(num_nodes=48, batch_pods=8, domain_universe=40,
+                      image_universe=24, podsel_universe=32,
+                      term_universe=16)
+    POLICY = Policy(
+        predicates=BASE_PREDS + ("MatchInterPodAffinity",),
+        priorities=BASE_PRIOS + (("SelectorSpreadPriority", 1),
+                                 ("ImageLocalityPriority", 1),
+                                 ("InterPodAffinityPriority", 1),
+                                 ("RackSpread", 1)),
+        service_anti_priorities=(("RackSpread", "rack"),))
+
+    def dots(self) -> list[tuple[str, tuple, tuple, tuple]]:
+        import re
+
+        import jax
+
+        caps = self.CAPS
+        nodes = [mk_node(f"n{i}", labels={ZONE: f"z{i % 3}", "rack": "r"})
+                 for i in range(4)]
+        state, batch, table = encode_cluster(nodes, [mk_pod("p")], caps,
+                                             ctx=mk_ctx(service_anti=True))
+        prows = build_policy_rows(self.POLICY, table, caps)
+        apply_pending_refreshes(state, table)
+        text = jax.jit(lambda s, b, rr: schedule_batch(
+            s, b, rr, self.POLICY, caps=caps, prows=prows)).lower(
+            state, batch, np.uint32(0)).as_text()
+
+        def dims(t):
+            return tuple(int(d) for d in t.split("x")[:-1])
+
+        out = []
+        for line in text.splitlines():
+            if "dot_general" not in line:
+                continue
+            prec = re.search(r"precision = \[(\w+), \w+\]", line).group(1)
+            lhs, rhs, res = re.search(
+                r": \(tensor<([^>]*)>, tensor<([^>]*)>\) -> tensor<([^>]*)>",
+                line).groups()
+            out.append((prec, dims(lhs), dims(rhs), dims(res)))
+        return out
+
+    def test_count_operand_dots_lower_at_highest(self):
+        c = self.CAPS
+        n, d, k = c.num_nodes, c.domain_universe, c.topology_slots
+        sites = {
+            # spread.py: per-zone / per-label-value sums and broadcasts
+            "domain sums": ((d, n), (n,), (d,)),
+            "domain broadcast": ((n, d), (d,), (n,)),
+            # priorities.py image_locality: image sizes in bytes, per pod
+            "image sizes": ((n, c.image_universe),
+                            (c.batch_pods, c.image_universe),
+                            (n, c.batch_pods)),
+            # interpod.py domain_aggregates / _slot_counts, per universe
+            "aggregate podsel": ((n, k, d), (n, c.podsel_universe),
+                                 (k, d, c.podsel_universe)),
+            "aggregate terms": ((n, k, d), (n, c.term_universe),
+                                (k, d, c.term_universe)),
+            "slot podsel": ((k, n, d), (k, d, c.podsel_universe),
+                            (k, n, c.podsel_universe)),
+            "slot terms": ((k, n, d), (k, d, c.term_universe),
+                           (k, n, c.term_universe)),
+        }
+        found = self.dots()
+        for site, shape in sites.items():
+            precs = [p for p, *s in found if tuple(s) == shape]
+            assert precs, f"{site}: no dot {shape} in the program"
+            assert set(precs) == {"HIGHEST"}, (site, precs)
+        # one-hot x one-hot memberships stay at DEFAULT (the cheap form)
+        assert any(p == "DEFAULT" for p, *_ in found)
+
+    def test_parity_with_domain_counts_past_256(self):
+        rng = np.random.RandomState(7)
+        zones = ["a", "b", ""]
+        nodes = [mk_node(f"n{i}", pods="400",
+                         labels={ZONE: zones[i % 3]} if zones[i % 3] else {})
+                 for i in range(5)]
+        web = {"app": "web"}
+        assigned = [mk_pod(f"a{i}", labels=web,
+                           node_name=f"n{rng.randint(5)}")
+                    for i in range(1200)]
+        per_zone = {z: sum(1 for p in assigned
+                           if zones[int(p.spec.node_name[1:]) % 3] == z)
+                    for z in ("a", "b")}
+        assert min(per_zone.values()) > 256, per_zone
+        pending = [mk_pod(f"p{i}", labels=web) for i in range(12)]
+        ctx = mk_ctx(services=[svc("s1", web)], all_pods=assigned + pending)
+        serial = SerialScheduler(
+            nodes, assigned, volume_ctx=ctx,
+            extra_priorities=frozenset({"SelectorSpreadPriority"}))
+        policy = Policy(predicates=BASE_PREDS,
+                        priorities=BASE_PRIOS + (("SelectorSpreadPriority", 1),))
+        want = serial.schedule(pending)
+        got = solve(nodes, pending, policy, assigned=assigned, ctx=ctx)
+        assert got == want
