@@ -90,12 +90,8 @@ settle, commit, kubelet).
 (obs/profiling.py) across the whole bench: the sampling host profiler
 rides every config and its collapsed flamegraph stacks land in
 --profile-out PATH (BENCH_PROFILE_OUT, default bench_profile.collapsed);
-the compile registry collects per-variant compile seconds and
-cost_analysis flops/bytes; and RESULT.bottleneck names the dominant
-stage per config (headline from pipeline busy fractions, defrag from
-probe-solve vs plan/execute split) with busy fractions, transfer bytes
-and compile-cost totals attached — "name the next wall" as a gated
-artifact.
+and the compile registry collects per-variant compile seconds and
+cost_analysis flops/bytes.
 """
 
 import faulthandler
@@ -253,7 +249,6 @@ def main() -> None:
         from kubernetes_tpu.obs import profiling
 
         profiling.PROFILER.start(cost_analysis=True)
-        RESULT["bottleneck"] = {}
 
     print(f"bench: devices={jax.devices()} nodes={n_nodes} pods={n_pods} "
           f"configs={configs}", file=sys.stderr, flush=True)
@@ -295,24 +290,6 @@ def main() -> None:
                     f"< gate {e2e_floor:.0f}")
         if metrics_snapshot:
             extras["headline_phase_hist"] = r.phase_hist
-        if profile:
-            # dominant stage over the timed wave: pipeline busy seconds
-            # when staged, phase CPU seconds otherwise
-            from kubernetes_tpu.obs import profiling
-
-            busy = (r.pipeline or {}).get("stage_busy_frac") or {}
-            if busy:
-                costs = {k: v * r.seconds for k, v in busy.items()}
-            else:
-                costs = {k: v * r.scheduled / 1e6 for k, v in
-                         r.metrics.get("phase_us_per_pod", {}).items()}
-            RESULT["bottleneck"]["headline"] = profiling.bottleneck_report(
-                "headline", costs,
-                stage_busy_frac=busy or None,
-                queue_depth_max=(r.pipeline or {}).get("queue_depth_max"),
-                transfer_bytes=r.transfers,
-                compile_totals=profiling.COMPILES.totals(),
-                wall_s=r.seconds)
 
     if "interpod" in configs:
         interpod_nodes = min(n_nodes, 5000)
@@ -965,11 +942,6 @@ def main() -> None:
         df_gang = int(os.environ.get("BENCH_DEFRAG_GANG", "8"))
         df_moves = int(os.environ.get("BENCH_DEFRAG_MAX_MOVES", "8"))
         df_seed = int(os.environ.get("BENCH_DEFRAG_SEED", "1234"))
-        defrag_tb0 = None
-        if profile:
-            from kubernetes_tpu.perf.harness import _transfer_counters
-
-            defrag_tb0 = _transfer_counters()
         r = run_defrag(n_nodes=df_nodes, gang_size=df_gang,
                        max_moves=df_moves, seed=df_seed)
         print(f"bench[defrag]: {r}", file=sys.stderr, flush=True)
@@ -996,24 +968,6 @@ def main() -> None:
             RESULT["error"] = (
                 f"defrag bench (seed {r.seed}): {r.double_binds} "
                 f"double-binds, {r.racy_writes} racy writes")
-        if profile:
-            # the defrag bill is probe solves vs everything else (plan
-            # + evict + reschedule); PERF.md Round 13's 18×1369 ms story
-            # becomes a gated verdict
-            from kubernetes_tpu.obs import profiling
-            from kubernetes_tpu.perf.harness import _transfer_counters
-
-            tb1 = _transfer_counters()
-            sim_s = r.sim_solves * r.sim_ms_per_solve / 1e3
-            wall = r.defrag_convergence_ms / 1e3
-            RESULT["bottleneck"]["defrag"] = profiling.bottleneck_report(
-                "defrag",
-                {"probe_solve": sim_s,
-                 "plan_and_execute": max(0.0, wall - sim_s)},
-                transfer_bytes={k: int(tb1[k] - defrag_tb0[k])
-                                for k in defrag_tb0},
-                compile_totals=profiling.COMPILES.totals(),
-                wall_s=wall)
 
     if "soak" in configs:
         from kubernetes_tpu.scenario.soak import run_soak

@@ -1,0 +1,7 @@
+"""Share of the window the dispatch stage waited at the ledger-dirty
+barrier (StagedPipeline.snapshot()["stage_busy_frac"]["barrier_wait"]),
+under open-loop arrivals."""
+
+
+def read(ctx):
+    return (ctx.get("stage_busy_frac") or {}).get("barrier_wait")

@@ -71,6 +71,10 @@ class BackoffQueue:
         self._mx: tuple | None = None
         self._added_at: dict[Hashable, float] = {}
         self._started_at: dict[Hashable, float] = {}
+        # optional sink for the same per-item timings (named queues only):
+        # wait_sink("queue_wait", add-to-pop seconds summed over a popped
+        # batch) and wait_sink("in_flight", one item's pop-to-done seconds)
+        self.wait_sink = None
 
     def _metrics(self) -> tuple | None:
         """(name, depth, adds, retries, queue_dur, work_dur) children for
@@ -142,7 +146,10 @@ class BackoffQueue:
         if mx is not None:
             started = self._started_at.pop(item, None)
             if started is not None:
-                mx[5].observe(time.monotonic() - started)
+                busy = time.monotonic() - started
+                mx[5].observe(busy)
+                if self.wait_sink is not None:
+                    self.wait_sink("in_flight", busy)
         if item in self._dirty:
             self._dirty.discard(item)
             self.add(item)
@@ -180,10 +187,15 @@ class BackoffQueue:
                     now = time.monotonic()
                     observe = mx[4].observe
                     added_pop = self._added_at.pop
+                    waited = 0.0
                     for item in items:
-                        observe(now - added_pop(item, now))
+                        wait = now - added_pop(item, now)
+                        observe(wait)
+                        waited += wait
                         self._started_at[item] = now
                     mx[1].set(len(self._queue))
+                    if self.wait_sink is not None:
+                        self.wait_sink("queue_wait", waited)
                 return items
             timeout = next_delay
             if deadline is not None:

@@ -28,10 +28,6 @@ question the first chip session asks before anything else. Four pillars:
     (`/debug/profile/device?seconds=N` -> artifact dir) so the first
     chip session is a curl, not a code change.
 
-`bottleneck_report()` folds pipeline busy fractions, phase CPU time,
-transfer bytes and compile cost into a single "name the next wall"
-verdict; bench.py --profile emits it as RESULT.bottleneck per config.
-
 Thread discipline: the sampler and capture threads never touch the
 event loop (lint R1 tier-3) and pace themselves with Event.wait, never
 time.sleep (tier-2).
@@ -480,63 +476,6 @@ class DeviceTraceCapture:
         t = self._thread
         if t is not None:
             t.join(timeout)
-
-
-# hint table keyed by dominant cost: what the named wall usually means
-# and the first lever to pull (ties into ROADMAP open items 1-3)
-BOTTLENECK_HINTS = {
-    "dispatch": "host->device submit bound: grow the batch or overlap "
-                "dispatch with encode",
-    "settle": "device->host readback bound: donate result buffers and "
-              "np.asarray only the sliced outputs",
-    "commit": "store write-back bound: widen commit fan-out or batch "
-              "bind writes",
-    "apply": "state apply bound: keep the row scatter fully on-device",
-    "encode": "host encode bound: vectorize pod/node packing",
-    "probe_solve": "defrag probe solves dominate: batch what-if solves "
-                   "on one device call or pre-warm the variant cache",
-}
-
-
-def bottleneck_report(config: str, costs: dict,
-                      *, stage_busy_frac: dict | None = None,
-                      queue_depth_max: dict | None = None,
-                      transfer_bytes: dict | None = None,
-                      compile_totals: dict | None = None,
-                      wall_s: float | None = None,
-                      hints: dict | None = None) -> dict:
-    """Fold the evidence into one verdict: `dominant` names the largest
-    cost bucket; busy fractions, queue high-waters, transfer bytes and
-    compile totals ride along so the report is auditable, and `hint`
-    says what that wall usually means."""
-    costs = {k: max(0.0, float(v)) for k, v in (costs or {}).items()}
-    dominant = max(costs, key=lambda k: costs[k]) if costs else "unknown"
-    total = sum(costs.values()) or 1.0
-    report: dict = {
-        "config": config,
-        "dominant": dominant,
-        "costs_seconds": {k: round(v, 4) for k, v in sorted(
-            costs.items(), key=lambda kv: -kv[1])},
-        "cost_fractions": {k: round(v / total, 4) for k, v in sorted(
-            costs.items(), key=lambda kv: -kv[1])},
-    }
-    if stage_busy_frac:
-        report["stage_busy_frac"] = {
-            k: round(float(v), 4) for k, v in stage_busy_frac.items()}
-    if queue_depth_max:
-        report["queue_depth_max"] = dict(queue_depth_max)
-    if transfer_bytes:
-        report["transfer_bytes"] = {
-            k: int(v) for k, v in transfer_bytes.items()}
-    if compile_totals:
-        report["compile"] = dict(compile_totals)
-    if wall_s is not None:
-        report["wall_seconds"] = round(float(wall_s), 3)
-    hint = (hints if hints is not None else BOTTLENECK_HINTS).get(
-        dominant)
-    if hint:
-        report["hint"] = hint
-    return report
 
 
 # host<->device transfer accounting: the settle-stage readback side.

@@ -27,14 +27,26 @@ Span lifecycle discipline is lint-enforced (R6 ``span-discipline``):
 ``finally``; ``begin_span`` is the sanctioned escape hatch for explicit
 cross-thread handoff and is tracked in the tracer's open-span table so
 orphans are still observable (``Tracer.open_spans``).
+
+Stage spans on the profiler's clock: ``stage(...)`` times one scheduler
+stage boundary as a ``jax.profiler.TraceAnnotation`` (a TraceMe, on the
+host clock the device trace uses, so a ``/debug/profile/device`` capture
+shows the stage rows beside the device's ops) and feeds the counters
+that stage already had: a ``scheduler_phase_duration_seconds`` phase, a
+pipeline busy total, and this module's retroactive Dapper child.
+``watch_gc`` adds one process-wide ``gc.callbacks`` hook that names
+collector pauses the same way (``ktpu.gc.gen1`` / ``ktpu.gc.gen2``).
+With no profiler capture open a TraceMe costs well under a microsecond.
 """
 
 from __future__ import annotations
 
+import gc
 import json
 import os
 import threading
 import time
+import weakref
 from collections import deque
 from dataclasses import dataclass
 
@@ -44,14 +56,6 @@ TRACE_ANNOTATION = "trace.ktpu.io/context"
 # export's thread names).  Order is the pipeline order.
 STAGE_TIDS = ("client", "apiserver", "encode", "dispatch", "settle",
               "commit", "kubelet")
-
-
-def wall_now() -> float:
-    """Wall-clock timestamp for span records. Lives HERE (obs/ sits
-    outside the R4 determinism lint scope) so solve-path modules can
-    timestamp trace spans without tripping seed-replay checks: a span's
-    ts never feeds a scheduling decision."""
-    return time.time()
 
 
 @dataclass(frozen=True)
@@ -326,6 +330,137 @@ class Tracer:
 
 
 TRACER = Tracer()
+
+
+_TRACE_ME = None
+
+
+def _trace_me():
+    """jax.profiler.TraceAnnotation, imported on first use (this module
+    is imported by planes that never touch jax)."""
+    global _TRACE_ME
+    if _TRACE_ME is None:
+        from jax.profiler import TraceAnnotation
+
+        _TRACE_ME = TraceAnnotation
+    return _TRACE_ME
+
+
+class stage:
+    """One stage boundary: ``with stage(name, ...) as st: <work>``.
+
+    - opens a TraceMe ``name`` (with ``batch=<id>`` metadata when given:
+      the scheduler's per-batch sequence number, so every row of one batch
+      carries one id);
+    - on exit adds the elapsed time to ``metrics.add_phase(phase, ...)``,
+      in thread CPU time where ``cpu`` (GIL waits on concurrent stage
+      threads excluded), wall time otherwise;
+    - adds the wall time to ``busy[0][busy[1]]`` (a pipeline busy total);
+    - records a retroactive Dapper child named ``tid`` on that row when
+      ``work_span`` is sampled (settable inside the block, with
+      ``status`` and ``attrs``).
+
+    ``st.elapsed`` holds the wall seconds once the block has exited.
+    """
+
+    __slots__ = ("name", "batch", "metrics", "phase", "cpu", "busy",
+                 "work_span", "tid", "attrs", "status", "elapsed", "_tm",
+                 "_t0", "_c0")
+
+    def __init__(self, name: str, *, batch: int | None = None,
+                 metrics=None, phase: str | None = None, cpu: bool = False,
+                 busy: tuple[dict, str] | None = None, work_span=None,
+                 tid: str | None = None, attrs: dict | None = None):
+        self.name = name
+        self.batch = batch
+        self.metrics = metrics
+        self.phase = phase
+        self.cpu = cpu
+        self.busy = busy
+        self.work_span = work_span
+        self.tid = tid
+        self.attrs = attrs
+        self.status = "ok"
+        self.elapsed = 0.0
+
+    def __enter__(self) -> "stage":
+        tm = _trace_me()
+        self._tm = tm(self.name) if self.batch is None \
+            else tm(self.name, batch=self.batch)
+        self._tm.__enter__()
+        if self.cpu:
+            self._c0 = time.thread_time()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        wall = time.perf_counter() - self._t0
+        cpu = time.thread_time() - self._c0 if self.cpu else wall
+        self._tm.__exit__(exc_type, exc, tb)
+        self.elapsed = wall
+        if self.phase is not None:
+            self.metrics.add_phase(self.phase, cpu)
+        if self.busy is not None:
+            totals, key = self.busy
+            totals[key] += wall
+        span = self.work_span
+        if span is not None and span.sampled:
+            TRACER.record_span(
+                self.tid, span.context, time.time() - wall, wall,
+                tid=self.tid,
+                status="error" if exc_type is not None else self.status,
+                attrs=self.attrs)
+
+
+# ---- collector pauses -----------------------------------------------------
+
+_GC_WATCHED: "weakref.WeakSet" = weakref.WeakSet()
+_GC_STATE: dict = {"t0": 0.0, "tm": None, "counters": None}
+
+
+def _gc_counters() -> tuple:
+    from kubernetes_tpu.obs import metrics as _metrics
+
+    fam = _metrics.REGISTRY.counter(
+        "process_gc_pause_seconds_total",
+        "Seconds the process spent in garbage collections, by generation.",
+        ("generation",))
+    return tuple(fam.labels(str(g)) for g in range(3))
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """gc.callbacks hook: every collection adds its pause to the
+    process counter and to each watched scheduler's ``gc_pause`` phase
+    (its metrics resolved now: the benchmark swaps them at a window's
+    start); gen1 and gen2 collections also open a TraceMe."""
+    gen = info["generation"]
+    st = _GC_STATE
+    if phase == "start":
+        if gen:
+            tm = _trace_me()(f"ktpu.gc.gen{gen}")
+            tm.__enter__()
+            st["tm"] = tm
+        st["t0"] = time.perf_counter()
+        return
+    pause = time.perf_counter() - st["t0"]
+    tm = st["tm"]
+    if tm is not None:
+        st["tm"] = None
+        tm.__exit__(None, None, None)
+    st["counters"][gen].inc(pause)
+    for owner in _GC_WATCHED:
+        owner.metrics.accrue("gc_pause", pause)
+
+
+def watch_gc(owner) -> None:
+    """Count collector pauses into ``owner.metrics.accrue("gc_pause",
+    ...)`` (not the phase histogram: that stays one observation per
+    batch phase). One hook per process, however many owners register."""
+    if _GC_STATE["counters"] is None:
+        _trace_me()  # never import inside a collection
+        _GC_STATE["counters"] = _gc_counters()
+        gc.callbacks.append(_on_gc)
+    _GC_WATCHED.add(owner)
 
 
 def pod_trace_context(pod) -> SpanContext | None:

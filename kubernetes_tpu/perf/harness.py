@@ -39,26 +39,10 @@ class ThroughputResult:
     # mesh runs: per-shard live-row occupancy + StateDB flush transfer
     # counters (bench[sharded] extras); empty without a mesh
     sharding: dict = field(default_factory=dict)
-    # host<->device transfer-byte deltas over the timed wave (upload:
-    # statedb_flush_bytes_total, readback: device_readback_bytes_total)
-    transfers: dict = field(default_factory=dict)
 
     def __str__(self) -> str:
         return (f"{self.scheduled} pods in {self.seconds:.2f}s = "
                 f"{self.pods_per_sec:.0f} pods/s over {self.batches} batches")
-
-
-def _transfer_counters() -> dict:
-    """Process-global transfer counters (the profiling plane's byte
-    ledger) — deltas around a timed wave attribute its traffic."""
-    from kubernetes_tpu.obs import REGISTRY
-    out = {}
-    for key, name in (("flush_bytes", "statedb_flush_bytes_total"),
-                      ("flush_transfers", "statedb_flush_transfers_total"),
-                      ("readback_bytes", "device_readback_bytes_total")):
-        fam = REGISTRY.get(name)
-        out[key] = float(fam.labels().value) if fam is not None else 0.0
-    return out
 
 
 def freeze_drill_heap() -> None:
@@ -137,11 +121,9 @@ async def _run(n_nodes: int, n_pods: int, caps: Capacities, policy: Policy,
     await asyncio.sleep(0)
 
     batches_before = sched.metrics.batches
-    transfers_before = _transfer_counters()
     t0 = time.perf_counter()
     done = await drain(n_pods)
     dt = time.perf_counter() - t0
-    transfers_after = _transfer_counters()
     result = ThroughputResult(
         scheduled=done,
         seconds=dt,
@@ -158,8 +140,6 @@ async def _run(n_nodes: int, n_pods: int, caps: Capacities, policy: Policy,
             "flush_transfers_total": sched.statedb.flush_transfers_total,
             "flush_full_total": sched.statedb.flush_full_total,
         } if mesh is not None else {}),
-        transfers={k: int(transfers_after[k] - transfers_before[k])
-                   for k in transfers_before},
     )
     sched.stop()
     return result

@@ -50,7 +50,8 @@ from kubernetes_tpu.obs.tracing import (
     TRACE_ANNOTATION,
     TRACER,
     pod_trace_context,
-    wall_now,
+    stage,
+    watch_gc,
 )
 from kubernetes_tpu.ops.solver import EXPLAIN_STAGES, schedule_batch
 from kubernetes_tpu.state import Capacities
@@ -193,10 +194,6 @@ class SchedulerMetrics:
             "Per-batch scheduling phase durations "
             "(encode/flush/dispatch/solve/settle_wait/bind/commit).",
             ("phase",), buckets=PHASE_BUCKETS_S)
-        self.trace_steps = r.histogram(
-            "scheduler_trace_step_duration_seconds",
-            "Scheduling-batch trace spans (StepTimer steps).",
-            ("step",), buckets=PHASE_BUCKETS_S)
         # pipeline saturation gauges, refreshed at scrape time from
         # StagedPipeline.snapshot() so the monitor can watch the same
         # busy fractions the bench extras report
@@ -240,7 +237,8 @@ class SchedulerMetrics:
             "binding_latency_microseconds", "Binding latency per pod.",
             buckets=LATENCY_BUCKETS_US))
         # cumulative host-plane phase costs (seconds): the per-phase
-        # breakdown of where a batch's host time goes
+        # breakdown of where a batch's host time goes, plus accrued waits
+        # (queue_wait, in_flight, gc_pause) that are no batch phase
         self.phase_s: dict = {}
         self.phase_pods = 0
 
@@ -335,6 +333,12 @@ class SchedulerMetrics:
     def add_phase(self, name: str, seconds: float) -> None:
         self.phase_s[name] = self.phase_s.get(name, 0.0) + seconds
         self._h_phase.labels(name).observe(seconds)
+
+    def accrue(self, name: str, seconds: float) -> None:
+        """Add to phase_s without a phase-histogram observation: a sum
+        of per-pod waits or of collector pauses is no batch phase (the
+        workqueue's and the gc counter's own families carry those)."""
+        self.phase_s[name] = self.phase_s.get(name, 0.0) + seconds
 
     def export_pipeline(self, snap: dict | None) -> None:
         """Mirror a StagedPipeline.snapshot() into the saturation
@@ -477,6 +481,12 @@ class Scheduler:
         self.queue = BackoffQueue(name="scheduler")
         self.backoff = Backoff(initial=0.05, max_duration=5.0)
         self.metrics = SchedulerMetrics()
+        # per-pod add-to-pop and pop-to-done waits, and collector pauses,
+        # accrue into whichever metrics object is current
+        self.queue.wait_sink = lambda name, s: self.metrics.accrue(name, s)
+        watch_gc(self)
+        # batch id: assigned at encode, the `batch` of every stage span
+        self._batch_seq = 0
         self.events = EventRecorder(store)
         self._assumed: set[str] = set()
         self._enqueue_time: dict[str, float] = {}
@@ -997,22 +1007,23 @@ class Scheduler:
         if shard is not None and not shard._stopped:
             shard.submit(entries)
             return
-        t0 = time.monotonic()
-        try:
-            self.events.record_grouped(entries)
-            self._event_flush_failures = 0
-        except Exception:  # noqa: BLE001 — events must not kill the driver
-            self._event_flush_failures = getattr(
-                self, "_event_flush_failures", 0) + 1
-            if self._event_flush_failures <= 3:
-                log.warning("event flush failed (attempt %d); retrying on "
-                            "next flush", self._event_flush_failures,
-                            exc_info=True)
-                self._pending_events = entries + self._pending_events
-            else:
-                log.error("event flush failed %d times; dropping %d events",
-                          self._event_flush_failures, len(entries))
-        self.metrics.add_phase("events_async", time.monotonic() - t0)
+        with stage("ktpu.events.install", metrics=self.metrics,
+                   phase="events_async"):
+            try:
+                self.events.record_grouped(entries)
+                self._event_flush_failures = 0
+            except Exception:  # noqa: BLE001 — events must not kill the loop
+                self._event_flush_failures = getattr(
+                    self, "_event_flush_failures", 0) + 1
+                if self._event_flush_failures <= 3:
+                    log.warning("event flush failed (attempt %d); retrying "
+                                "on next flush", self._event_flush_failures,
+                                exc_info=True)
+                    self._pending_events = entries + self._pending_events
+                else:
+                    log.error("event flush failed %d times; dropping %d "
+                              "events", self._event_flush_failures,
+                              len(entries))
 
     async def _drain_events_async(self) -> None:
         """Make every buffered/sharded event visible (request-response
@@ -1148,8 +1159,9 @@ class Scheduler:
         inflight = self._inflight_q or (
             self._staged is not None and self._staged.inflight)
         effective_wait = 0 if inflight else wait
-        keys = await self.queue.get_batch(self.caps.batch_pods,
-                                          wait=effective_wait)
+        with stage("ktpu.queue.get_batch"):
+            keys = await self.queue.get_batch(self.caps.batch_pods,
+                                              wait=effective_wait)
         if not keys:
             settled += await self._asettle_inflight()
             if self._staged is not None and self._staged.inflight:
@@ -1171,9 +1183,10 @@ class Scheduler:
             self._requeue_keys(keys)
             raise
 
-    async def _schedule_batch(self, keys: list[str]) -> int:
-        t_phase = time.thread_time()
-        t_enc_wall = wall_now()
+    def _encode_batch(self, keys: list[str]):
+        """Encode the popped keys into a packed blob pair; returns (fblob,
+        iblob, pods, live_keys, gang_groups). Deleted or already-bound
+        pods drop out; a pod over capacity fails on its own."""
         fblob, iblob = self._acquire_blobs()
         pods: list[Pod] = []
         live_keys: list[str] = []
@@ -1219,8 +1232,7 @@ class Scheduler:
                     self.encode_cache.encode_packed_into(fblob, iblob, i,
                                                          pod)
         if not pods:
-            self._release_blobs((fblob, iblob))
-            return await self._asettle_inflight()
+            return fblob, iblob, pods, live_keys, gang_groups
         # unused tail rows of a reused blob must not leak the previous
         # batch's encodings (valid flags in particular)
         if len(pods) < self.caps.batch_pods:
@@ -1238,9 +1250,29 @@ class Scheduler:
                 if gid:
                     gid_col[i] = gid
                     gmin_col[i] = gmin
+        return fblob, iblob, pods, live_keys, gang_groups
+
+    async def _schedule_batch(self, keys: list[str]) -> int:
+        self._batch_seq += 1
+        seq = self._batch_seq
+        batch_span = None
         # host-phase costs accrue THREAD CPU time (see _apply_batch): wall
         # time on the loop includes GIL waits on concurrent stage threads
-        self.metrics.add_phase("encode", time.thread_time() - t_phase)
+        with stage("ktpu.encode", batch=seq, metrics=self.metrics,
+                   phase="encode", cpu=True, tid="encode") as st:
+            fblob, iblob, pods, live_keys, gang_groups = \
+                self._encode_batch(keys)
+            if pods and not self._extenders:
+                # the batch span: adopted from the first pod that carries
+                # a sampled trace.ktpu.io/context annotation (stitching
+                # the client/apiserver spans), else a rate-sampled root.
+                # Explicit handoff — the span rides the queue item across
+                # stage threads and ends at commit.
+                batch_span = st.work_span = self._begin_batch_span(pods)
+                st.attrs = {"pods": len(pods)}
+        if not pods:
+            self._release_blobs((fblob, iblob))
+            return await self._asettle_inflight()
         self.metrics.phase_pods += len(pods)
 
         if self._extenders:
@@ -1249,21 +1281,11 @@ class Scheduler:
                                                            fblob, iblob)
             finally:
                 self._release_blobs((fblob, iblob))
-        # the batch span: adopted from the first pod that carries a sampled
-        # trace.ktpu.io/context annotation (stitching the client/apiserver
-        # spans), else a rate-sampled root. Explicit handoff — the span
-        # rides the queue item across stage threads and ends at commit.
-        batch_span = self._begin_batch_span(pods)
-        if batch_span.sampled:
-            TRACER.record_span("encode", batch_span.context, t_enc_wall,
-                               wall_now() - t_enc_wall, tid="encode",
-                               attrs={"pods": len(pods)})
         if self._staged is not None and not self._stopped:
             return await self._schedule_batch_staged(
-                pods, live_keys, fblob, iblob, gang_groups, batch_span)
+                pods, live_keys, fblob, iblob, gang_groups, batch_span, seq)
 
         timer = StepTimer(f"scheduling batch of {len(pods)}",
-                          step_hist=self.metrics.trace_steps,
                           trace_span=batch_span)
         from kubernetes_tpu.state.pod_batch import packed_batch_flags
 
@@ -1279,29 +1301,34 @@ class Scheduler:
             # a dirty flush would re-upload host truth that misses the
             # in-flight batches' charges: settle them first
             settled += await self._asettle_inflight()
-        t_phase = time.thread_time()
-        state = self.statedb.flush()
-        self.metrics.add_phase("flush", time.thread_time() - t_phase)
+        with stage("ktpu.dispatch.flush", batch=seq, metrics=self.metrics,
+                   phase="flush", cpu=True):
+            state = self.statedb.flush()
         timer.step("encode + flush")
 
         t0 = time.monotonic()
-        try:
-            result = await self._dispatch_guarded(schedule_fn, state, fblob,
-                                                  iblob, victims, live_keys)
-        except _SolveFailed as e:
-            self.metrics.add_phase("dispatch", time.monotonic() - t0)
+        failed = None
+        with stage("ktpu.dispatch.solve", batch=seq, metrics=self.metrics,
+                   phase="dispatch"):
+            try:
+                result = await self._dispatch_guarded(
+                    schedule_fn, state, fblob, iblob, victims, live_keys)
+            except _SolveFailed as e:
+                failed = e
+            else:
+                self._rr = result.rr_end
+                try:
+                    # start the device->host copy now; by settle time
+                    # (after the next dispatch) it is usually already on
+                    # the host
+                    result.assignments.copy_to_host_async()
+                except AttributeError:
+                    pass
+        if failed is not None:
             self._release_blobs((fblob, iblob))
             batch_span.end("error")
             return settled + await self._recover_solve_failure(
-                pods, live_keys, gang_groups, e)
-        self._rr = result.rr_end
-        try:
-            # start the device->host copy now; by settle time (after the
-            # next dispatch) it is usually already on the host
-            result.assignments.copy_to_host_async()
-        except AttributeError:
-            pass
-        self.metrics.add_phase("dispatch", time.monotonic() - t0)
+                pods, live_keys, gang_groups, failed)
         timer.step("device dispatch")
         # start the blocking host fetch NOW in a worker thread: by settle
         # time (up to pipeline_depth dispatches later) the round trip has
@@ -1355,7 +1382,7 @@ class Scheduler:
     async def _schedule_batch_staged(self, pods: list[Pod],
                                      live_keys: list[str], fblob, iblob,
                                      gang_groups: dict,
-                                     batch_span=None) -> int:
+                                     batch_span=None, seq=None) -> int:
         """Hand one encoded batch to the staged pipeline: flush + solve +
         readback + ledger commit run in stage threads while this loop
         encodes the next batch (unconditional prefetch — the overlap the
@@ -1374,10 +1401,10 @@ class Scheduler:
         with self._state_lock:
             victims, vslots = self._build_victims(flags)
         work = _BatchWork(pods, live_keys, (fblob, iblob), flags,
-                          schedule_fn, victims, vslots, gang_groups)
+                          schedule_fn, victims, vslots, gang_groups, seq)
         work.span = batch_span
         self._loop_calls.bind(asyncio.get_running_loop())
-        await self._staged.wait_capacity()
+        await self._staged.wait_capacity(seq)
         self._staged.submit(work)
         settled = self._take_staged_settled()
         if len(self.queue) == 0:
@@ -1849,13 +1876,17 @@ class Scheduler:
             explain = np.asarray(result.explain_counts)
             record_readback(explain)
             explain_rows = explain[:len(pods)].tolist()
-        scheduled, committed, any_rejected = self._apply_batch(
-            result, pods, live_keys, blobs, flags, rows, preempt_rows,
-            victim_counts, gang_groups, vslots, timer,
-            explain_rows=explain_rows, span=timer.trace_span)
-        self._commit_ledger(result, blobs[0], committed, any_rejected,
-                            flags, adopted)
-        self._release_blobs(blobs)
+        with stage("ktpu.apply", metrics=self.metrics, phase="bind",
+                   cpu=True):
+            scheduled, committed, any_rejected = self._apply_batch(
+                result, pods, live_keys, blobs, flags, rows, preempt_rows,
+                victim_counts, gang_groups, vslots, timer,
+                explain_rows=explain_rows, span=timer.trace_span)
+        with stage("ktpu.commit.ledger", metrics=self.metrics,
+                   phase="commit", cpu=True):
+            self._commit_ledger(result, blobs[0], committed, any_rejected,
+                                flags, adopted)
+            self._release_blobs(blobs)
         timer.step("bind + commit")
         timer.log_if_long(0.1 * len(pods))
         return scheduled
@@ -1871,12 +1902,15 @@ class Scheduler:
         store, and buffer the per-pod events. Runs ON the event loop (in
         staged mode the commit thread marshals it here via LoopCalls) so
         every store write stays loop-serialized. Returns (scheduled,
-        committed, any_rejected) for _commit_ledger."""
+        committed, any_rejected) for _commit_ledger. Callers time it as
+        the `bind` phase in THREAD CPU time: with stage threads
+        overlapping the loop, wall time includes GIL waits on a concurrent
+        solve's trace/compile — CPU time is the stable drift signal the
+        phase gates watch (wall == cpu when uncontended)."""
         scheduled = 0
         committed: list[tuple[Pod, str, int]] = []
         any_rejected = False
         t_bind = time.monotonic()
-        t_bind_cpu = time.thread_time()
         # partition the batch: assigned rows to bind vs solver rejections
         name_of = self.statedb.table.name_of
         event_entries: list[tuple[Pod, str, str, str]] = []
@@ -2039,11 +2073,6 @@ class Scheduler:
                 except RuntimeError:   # sync stop() path: no running loop
                     self._flush_events()
         dt_bind = time.monotonic() - t_bind
-        # phase cost in THREAD CPU time: with stage threads overlapping the
-        # loop, wall time here includes GIL waits on a concurrent solve's
-        # trace/compile — CPU time is the stable drift signal the phase
-        # gates watch (wall == cpu when uncontended)
-        self.metrics.add_phase("bind", time.thread_time() - t_bind_cpu)
         if scheduled:
             # per-pod binding latency (the batch amortizes one write loop)
             self.metrics.binding_latency.append(dt_bind / scheduled)
@@ -2081,8 +2110,9 @@ class Scheduler:
         """Fold one applied batch into the StateDB ledgers. Safe OFF the
         loop (the staged commit thread calls it directly): everything runs
         under the host-state lock, against host/device arrays the loop
-        never mutates mid-batch."""
-        t_commit = time.thread_time()
+        never mutates mid-batch. Callers time it as the `commit` phase, in
+        CPU time (in the commit thread the dispatch thread may be tracing
+        a new solver variant, GIL-heavy)."""
         with self._state_lock:
             if any_rejected:
                 # the solver output charges pods whose binding failed: keep
@@ -2102,10 +2132,6 @@ class Scheduler:
                 self.statedb.commit_batch(
                     result, fblob, committed, replace_device=not adopted,
                     coverage=ledger_coverage(self.policy, flags))
-        # CPU time, not wall: under the staged pipeline this runs in the
-        # commit thread while the dispatch thread may be tracing a new
-        # solver variant (GIL-heavy) — see _apply_batch's bind phase note
-        self.metrics.add_phase("commit", time.thread_time() - t_commit)
 
     def _build_victims(self, flags):
         """Victim-candidate table for this batch: the StateDB's accounted

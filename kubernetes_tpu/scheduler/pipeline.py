@@ -41,7 +41,7 @@ from collections import deque
 import numpy as np
 
 from kubernetes_tpu.obs.profiling import record_readback
-from kubernetes_tpu.obs.tracing import TRACER, wall_now
+from kubernetes_tpu.obs.tracing import stage
 
 log = logging.getLogger(__name__)
 
@@ -132,24 +132,21 @@ class EventShard:
 
     def _install(self, built_groups, entries, attempt) -> None:
         """Publish pre-built event groups (runs on the loop)."""
-        t0 = time.monotonic()
-        try:
-            for sub, built, keys, event_type, reason in built_groups:
-                self._recorder.install_many(sub, built, keys, event_type,
-                                            reason)
-            self.installed_batches += 1
-        except Exception:  # noqa: BLE001 — events are best-effort
-            self.outstanding -= 1
-            if attempt < 3:
-                log.warning("event install failed (attempt %d); retrying",
-                            attempt + 1, exc_info=True)
-                self.submit(entries, attempt + 1)
-            else:
-                log.error("event install failed %d times; dropping %d "
-                          "events", attempt + 1, len(entries))
-            return
-        finally:
-            self._recorder_metrics_hook(time.monotonic() - t0)
+        with stage("ktpu.events.install") as st:
+            try:
+                for sub, built, keys, event_type, reason in built_groups:
+                    self._recorder.install_many(sub, built, keys,
+                                                event_type, reason)
+                self.installed_batches += 1
+            except Exception:  # noqa: BLE001 — events are best-effort
+                if attempt < 3:
+                    log.warning("event install failed (attempt %d); "
+                                "retrying", attempt + 1, exc_info=True)
+                    self.submit(entries, attempt + 1)
+                else:
+                    log.error("event install failed %d times; dropping %d "
+                              "events", attempt + 1, len(entries))
+        self._recorder_metrics_hook(st.elapsed)
         self.outstanding -= 1
 
     # overridable seam: the scheduler points this at
@@ -233,11 +230,12 @@ class EventShard:
                 except IndexError:
                     break
                 built_groups = []
-                for event_type, reason, sub in _group_entries(entries):
-                    built, keys = self._recorder.build_many(
-                        sub, event_type, reason)
-                    built_groups.append((sub, built, keys, event_type,
-                                         reason))
+                with stage("ktpu.events.build"):
+                    for event_type, reason, sub in _group_entries(entries):
+                        built, keys = self._recorder.build_many(
+                            sub, event_type, reason)
+                        built_groups.append((sub, built, keys, event_type,
+                                             reason))
                 self.built_entries += len(entries)
                 self._calls.push(
                     lambda g=built_groups, e=entries, a=attempt:
@@ -252,16 +250,18 @@ class _BatchWork:
     item (contextvars do not cross the stage-thread boundaries): stage
     work is recorded retroactively against it, and whichever path
     finishes the batch — commit, solve-failure landing, or a kill-path
-    _drop — owns ending it."""
+    _drop — owns ending it. `seq` is the scheduler's batch id (assigned at
+    encode), the `batch` metadata of every stage span of this batch."""
 
     __slots__ = ("pods", "live_keys", "blobs", "flags", "schedule_fn",
                  "victims", "vslots", "gang_groups", "result",
                  "assignments", "rows", "preempt_rows", "victim_counts",
                  "error", "solve_span", "active_counted", "span",
-                 "explain_rows")
+                 "explain_rows", "seq")
 
     def __init__(self, pods, live_keys, blobs, flags, schedule_fn,
-                 victims, vslots, gang_groups):
+                 victims, vslots, gang_groups, seq=None):
+        self.seq = seq
         self.pods = pods
         self.live_keys = live_keys
         self.blobs = blobs
@@ -314,9 +314,11 @@ class StagedPipeline:
         self.submitted = 0
         self.completed = 0
         self.dropped = 0
-        # occupancy instrumentation (satellite: bench `extras`)
+        # occupancy: seconds each stage worked, plus two waits — dispatch
+        # held at the ledger-dirty barrier, and an apply closure queued
+        # for the loop (commit thread)
         self.busy = {"dispatch": 0.0, "settle": 0.0, "commit": 0.0,
-                     "apply": 0.0}
+                     "apply": 0.0, "barrier_wait": 0.0, "apply_wait": 0.0}
         self._qmax = {"dispatch": 0, "settle": 0, "commit": 0}
         self._started: float | None = None
 
@@ -336,16 +338,19 @@ class StagedPipeline:
         self._ensure_threads()
         self._dispatch_wake.set()
 
-    async def wait_capacity(self) -> None:
+    async def wait_capacity(self, batch: int | None = None) -> None:
         """Block (yielding to the loop) until a pipeline slot frees up.
         The yields are what let marshalled apply closures run, so waiting
         here IS making progress."""
         import asyncio
 
-        while self.inflight >= self.depth:
-            self._calls.drain()
-            if self.inflight >= self.depth:
-                await asyncio.sleep(0.0005)
+        if self.inflight < self.depth:
+            return
+        with stage("ktpu.loop.wait_capacity", batch=batch):
+            while self.inflight >= self.depth:
+                self._calls.drain()
+                if self.inflight >= self.depth:
+                    await asyncio.sleep(0.0005)
 
     async def drain(self, timeout: float = 60.0) -> None:
         """Await until every submitted batch fully commits (loop
@@ -472,7 +477,10 @@ class StagedPipeline:
         adoption, FIFO. Loop access only through LoopCalls."""
         sched = self.sched
         while not self._stopped:
-            if not self._dispatch_wake.wait(timeout=_IDLE_WAIT_S):
+            # the device's only feeder parks here: no batch to dispatch
+            with stage("ktpu.dispatch.idle"):
+                woke = self._dispatch_wake.wait(timeout=_IDLE_WAIT_S)
+            if not woke:
                 continue
             self._dispatch_wake.clear()
             while True:
@@ -484,12 +492,17 @@ class StagedPipeline:
                     self._drop(work)
                     continue
                 # ledger-dirty barrier: host truth changed (external bind,
-                # rejected binding rollback) — the re-upload must not
-                # overwrite charges still in flight downstream
+                # rejected binding rollback, a delete) — the re-upload
+                # must not overwrite charges still in flight downstream
                 with self._dcond:
-                    while (self._active > 0 and not self.killed
-                           and sched.statedb.ledger_dirty):
-                        self._dcond.wait(0.05)
+                    if (self._active > 0 and not self.killed
+                            and sched.statedb.ledger_dirty):
+                        with stage("ktpu.dispatch.barrier_wait",
+                                   batch=work.seq,
+                                   busy=(self.busy, "barrier_wait")):
+                            while (self._active > 0 and not self.killed
+                                   and sched.statedb.ledger_dirty):
+                                self._dcond.wait(0.05)
                     if self.killed:
                         self.dropped += 1
                         if work.span is not None:
@@ -497,53 +510,49 @@ class StagedPipeline:
                         continue
                     self._active += 1
                     work.active_counted = True
-                t0 = time.perf_counter()
-                t0_wall = wall_now()
-                t0_cpu = time.thread_time()
-                try:
-                    with sched._state_lock:
-                        state = sched.statedb.flush()
-                    sched.metrics.add_phase("flush",
-                                            time.thread_time() - t0_cpu)
-                    result = None
-                    last: Exception | None = None
-                    for attempt in (1, 2):
-                        try:
-                            result = self._solve(work, state)
-                            break
-                        except Exception as e:  # noqa: BLE001
-                            last = e
-                            sched.metrics.solve_failure_inc()
-                            if attempt == 1:
-                                sched.metrics.solve_retry_inc()
-                                log.warning(
-                                    "device solve failed (attempt 1/2): "
-                                    "%s; retrying", e)
-                    if result is None:
-                        work.error = last
-                    else:
-                        sched._rr = result.rr_end
-                        with sched._state_lock:
-                            sched.statedb.adopt_result(result)
-                        try:
-                            result.assignments.copy_to_host_async()
-                        except AttributeError:
-                            pass
-                        work.result = result
-                except Exception as e:  # noqa: BLE001 — flush/adopt
-                    work.error = e
-                span = time.perf_counter() - t0
-                work.solve_span = span
-                self.busy["dispatch"] += span
-                if work.span is not None and work.span.sampled:
-                    # retroactive child: flush + solve + adopt on this row
-                    TRACER.record_span(
-                        "dispatch", work.span.context, t0_wall, span,
-                        tid="dispatch",
-                        status="error" if work.error is not None else "ok")
-                sched.metrics.add_phase("dispatch", span)
+                metrics = sched.metrics
+                # flush + solve + adopt: the dispatch stage's work
+                with stage("ktpu.dispatch.solve", batch=work.seq,
+                           metrics=metrics, phase="dispatch",
+                           busy=(self.busy, "dispatch"),
+                           work_span=work.span, tid="dispatch") as st:
+                    try:
+                        with stage("ktpu.dispatch.flush", batch=work.seq,
+                                   metrics=metrics, phase="flush",
+                                   cpu=True), sched._state_lock:
+                            state = sched.statedb.flush()
+                        result = None
+                        last: Exception | None = None
+                        for attempt in (1, 2):
+                            try:
+                                result = self._solve(work, state)
+                                break
+                            except Exception as e:  # noqa: BLE001
+                                last = e
+                                metrics.solve_failure_inc()
+                                if attempt == 1:
+                                    metrics.solve_retry_inc()
+                                    log.warning(
+                                        "device solve failed (attempt "
+                                        "1/2): %s; retrying", e)
+                        if result is None:
+                            work.error = last
+                        else:
+                            sched._rr = result.rr_end
+                            with sched._state_lock:
+                                sched.statedb.adopt_result(result)
+                            try:
+                                result.assignments.copy_to_host_async()
+                            except AttributeError:
+                                pass
+                            work.result = result
+                    except Exception as e:  # noqa: BLE001 — flush/adopt
+                        work.error = e
+                    if work.error is not None:
+                        st.status = "error"
+                work.solve_span = st.elapsed
                 if work.error is None:
-                    sched.metrics.algorithm_latency.append(span)
+                    metrics.algorithm_latency.append(st.elapsed)
                 if self.killed:
                     # the settle thread may already be gone: an append now
                     # would strand the batch (and orphan its span)
@@ -611,38 +620,35 @@ class StagedPipeline:
                     self._drop(work)
                     continue
                 if work.error is None:
-                    t0 = time.perf_counter()
-                    t0_wall = wall_now()
-                    try:
-                        n = len(work.pods)
-                        work.assignments = np.asarray(
-                            work.result.assignments)
-                        work.rows = work.assignments[:n].tolist()
-                        read = [work.assignments]
-                        if work.vslots is not None:
-                            preempt = np.asarray(work.result.preempt_node)
-                            victims = np.asarray(work.result.victim_count)
-                            work.preempt_rows = preempt[:n].tolist()
-                            work.victim_counts = victims[:n].tolist()
-                            read += [preempt, victims]
-                        if (work.flags.explain
-                                and work.result.explain_counts is not None):
-                            explain = np.asarray(
-                                work.result.explain_counts)
-                            work.explain_rows = explain[:n].tolist()
-                            read.append(explain)
-                        record_readback(*read)
-                    except Exception as e:  # noqa: BLE001 — transport
-                        work.error = e  # routed into solve-failure recovery
-                    dt = time.perf_counter() - t0
-                    self.busy["settle"] += dt
-                    if work.span is not None and work.span.sampled:
-                        TRACER.record_span(
-                            "settle", work.span.context, t0_wall, dt,
-                            tid="settle",
-                            status="error" if work.error is not None
-                            else "ok")
-                    sched.metrics.add_phase("settle_wait", dt)
+                    with stage("ktpu.settle.readback", batch=work.seq,
+                               metrics=sched.metrics, phase="settle_wait",
+                               busy=(self.busy, "settle"),
+                               work_span=work.span, tid="settle") as st:
+                        try:
+                            n = len(work.pods)
+                            work.assignments = np.asarray(
+                                work.result.assignments)
+                            work.rows = work.assignments[:n].tolist()
+                            read = [work.assignments]
+                            if work.vslots is not None:
+                                preempt = np.asarray(
+                                    work.result.preempt_node)
+                                victims = np.asarray(
+                                    work.result.victim_count)
+                                work.preempt_rows = preempt[:n].tolist()
+                                work.victim_counts = victims[:n].tolist()
+                                read += [preempt, victims]
+                            if (work.flags.explain and
+                                    work.result.explain_counts is not None):
+                                explain = np.asarray(
+                                    work.result.explain_counts)
+                                work.explain_rows = explain[:n].tolist()
+                                read.append(explain)
+                            record_readback(*read)
+                        except Exception as e:  # noqa: BLE001 — transport
+                            # routed into solve-failure recovery
+                            work.error = e
+                            st.status = "error"
                 if self.killed:
                     self._drop(work)  # commit thread may already be gone
                     continue
@@ -681,29 +687,39 @@ class StagedPipeline:
                         lambda w=work: self._finish(w, 0))
                     self._progress.set()
                     continue
+                started = threading.Event()
                 done = threading.Event()
                 box: dict = {}
 
-                def apply(work=work, done=done, box=box):
-                    t0 = time.perf_counter()
+                def apply(work=work, started=started, done=done, box=box):
+                    started.set()
                     try:
-                        box["out"] = sched._apply_batch(
-                            work.result, work.pods, work.live_keys,
-                            work.blobs, work.flags, work.rows,
-                            work.preempt_rows, work.victim_counts,
-                            work.gang_groups, work.vslots, None,
-                            explain_rows=work.explain_rows,
-                            span=work.span)
-                    except Exception:  # noqa: BLE001
-                        log.exception("staged apply failed; requeueing "
-                                      "the batch")
-                        sched._requeue_keys(work.live_keys)
-                        sched.statedb.mark_ledger_dirty()
+                        with stage("ktpu.apply", batch=work.seq,
+                                   metrics=sched.metrics, phase="bind",
+                                   cpu=True, busy=(self.busy, "apply")):
+                            try:
+                                box["out"] = sched._apply_batch(
+                                    work.result, work.pods, work.live_keys,
+                                    work.blobs, work.flags, work.rows,
+                                    work.preempt_rows, work.victim_counts,
+                                    work.gang_groups, work.vslots, None,
+                                    explain_rows=work.explain_rows,
+                                    span=work.span)
+                            except Exception:  # noqa: BLE001
+                                log.exception("staged apply failed; "
+                                              "requeueing the batch")
+                                sched._requeue_keys(work.live_keys)
+                                sched.statedb.mark_ledger_dirty()
                     finally:
-                        self.busy["apply"] += time.perf_counter() - t0
                         done.set()
 
                 self._calls.push(apply)
+                # the batch's closure queued behind the loop's other work
+                with stage("ktpu.commit.apply_wait", batch=work.seq,
+                           busy=(self.busy, "apply_wait")):
+                    while not started.wait(timeout=0.1):
+                        if self.killed:
+                            break
                 while not done.wait(timeout=0.1):
                     if self.killed:
                         break
@@ -712,24 +728,21 @@ class StagedPipeline:
                     continue
                 scheduled = 0
                 out = box.get("out")
-                t0 = time.perf_counter()
-                t0_wall = wall_now()
-                if out is not None:
-                    scheduled, committed, any_rejected = out
-                    try:
-                        sched._commit_ledger(work.result, work.blobs[0],
-                                             committed, any_rejected,
-                                             work.flags, adopted=True)
-                    except Exception:  # noqa: BLE001
-                        log.exception("staged ledger commit failed; "
-                                      "marking dirty")
-                        sched.statedb.mark_ledger_dirty()
-                sched._release_blobs(work.blobs)
-                dt = time.perf_counter() - t0
-                self.busy["commit"] += dt
-                if work.span is not None and work.span.sampled:
-                    TRACER.record_span("commit", work.span.context,
-                                       t0_wall, dt, tid="commit")
+                with stage("ktpu.commit.ledger", batch=work.seq,
+                           metrics=sched.metrics, phase="commit", cpu=True,
+                           busy=(self.busy, "commit"),
+                           work_span=work.span, tid="commit"):
+                    if out is not None:
+                        scheduled, committed, any_rejected = out
+                        try:
+                            sched._commit_ledger(
+                                work.result, work.blobs[0], committed,
+                                any_rejected, work.flags, adopted=True)
+                        except Exception:  # noqa: BLE001
+                            log.exception("staged ledger commit failed; "
+                                          "marking dirty")
+                            sched.statedb.mark_ledger_dirty()
+                    sched._release_blobs(work.blobs)
                 if work.span is not None:
                     work.span.end("ok")
                 with self._dcond:

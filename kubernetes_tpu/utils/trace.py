@@ -3,8 +3,8 @@
 The util/trace.Trace analog (reference apiserver/pkg/util/trace/trace.go:28-90;
 the scheduler wraps Schedule with trace.Step(...) + LogIfLong(100ms),
 core/generic_scheduler.go:89-126), extended into a span tracer: every
-finished trace can feed a registry histogram family (per-step durations)
-and a structured-JSON sink, while the log line stays thresholded.
+finished trace can feed a structured-JSON sink, while the log line stays
+thresholded.
 
 The sink is process-global: `set_trace_sink(callable | path | None)`, or
 the KTPU_TRACE_FILE environment variable (one JSON object per line,
@@ -86,18 +86,15 @@ if os.environ.get("KTPU_TRACE_FILE"):
 
 
 class StepTimer:
-    """Named step spans off one start point. `step_hist`, when given, is a
-    histogram family labeled by step name; each finished trace observes
-    its per-step durations there (log_if_long is the finish point).
-    `trace_span`, when given, is an obs/tracing.py Span owned by this
-    timer: export() records each step as a child span and ends it."""
+    """Named step spans off one start point (log_if_long is the finish
+    point). `trace_span`, when given, is an obs/tracing.py Span owned by
+    this timer: export() records each step as a child span and ends it."""
 
-    def __init__(self, name: str, step_hist=None, trace_span=None):
+    def __init__(self, name: str, trace_span=None):
         self.name = name
         self.start = time.monotonic()
         self.start_wall = time.time()
         self.steps: list[tuple[str, float]] = []
-        self.step_hist = step_hist
         self.trace_span = trace_span
 
     def step(self, label: str) -> None:
@@ -116,15 +113,11 @@ class StepTimer:
         return out
 
     def export(self, total: float | None = None) -> None:
-        """Feed the step histogram, the JSON sink, and the distributed
-        trace (no-ops when none is configured)."""
+        """Feed the JSON sink and the distributed trace (no-ops when
+        neither is configured)."""
         spans = None
-        if self.step_hist is not None:
-            spans = self.spans()
-            for label, dur in spans:
-                self.step_hist.labels(label).observe(dur)
         if _sink is not None:
-            spans = spans if spans is not None else self.spans()
+            spans = self.spans()
             _sink({"ts": time.time(), "name": self.name,
                    "total_ms": round(1e3 * (total if total is not None
                                             else self.total()), 3),

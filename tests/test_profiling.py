@@ -2,7 +2,7 @@
 under a ManualClock, stage-thread attribution through the staged
 pipeline, per-variant compile accounting, the CPU-fallback device-memory
 monitor, the /debug/pprof HTTP surface, a sampler overhead guard, and
-the tier-1 `bench.py --smoke --profile` RESULT.bottleneck gate."""
+the tier-1 `bench.py --smoke --profile` gate."""
 
 import asyncio
 import json
@@ -24,7 +24,6 @@ from kubernetes_tpu.obs.profiling import (
     DeviceMemoryMonitor,
     ProfilingPlane,
     SamplingProfiler,
-    bottleneck_report,
     record_readback,
 )
 from kubernetes_tpu.utils.clock import ManualClock
@@ -350,25 +349,6 @@ def test_statedb_flush_and_readback_transfer_counters():
     assert record_readback() == 0
 
 
-# ---- bottleneck report ----
-
-
-def test_bottleneck_report_shape():
-    rep = bottleneck_report(
-        "headline",
-        {"dispatch": 0.1, "settle": 0.6, "commit": 0.3},
-        stage_busy_frac={"settle": 0.61},
-        queue_depth_max={"settle": 4},
-        transfer_bytes={"flush_bytes": 1024},
-        compile_totals={"variants": 2},
-        wall_s=1.0)
-    assert rep["dominant"] == "settle"
-    assert rep["cost_fractions"]["settle"] == 0.6
-    assert list(rep["costs_seconds"]) == ["settle", "commit", "dispatch"]
-    assert "readback" in rep["hint"]
-    assert bottleneck_report("x", {})["dominant"] == "unknown"
-
-
 # ---- HTTP surface: /debug/pprof + /debug/profile/device ----
 
 
@@ -502,13 +482,14 @@ def test_sampler_overhead_bounded():
     assert prof._m_walk.labels().count >= 5
 
 
-# ---- tier-1 gate: bench --smoke --profile emits RESULT.bottleneck ----
+# ---- tier-1 gate: bench --smoke --profile ----
 
 
 def test_bench_smoke_profile_mode(tmp_path):
-    """bench.py --smoke --profile must emit RESULT.bottleneck naming a
-    dominant stage for headline + defrag and write the collapsed-stack
-    artifact; drift in the profiling wiring breaks this, not a nightly."""
+    """bench.py --smoke --profile must run headline + defrag with the
+    plane on, report the pipeline's busy fractions (the stage waits
+    included) and write the collapsed-stack artifact; drift in the
+    profiling wiring breaks this, not a nightly."""
     repo = Path(__file__).resolve().parents[1]
     out = tmp_path / "bench_profile.collapsed"
     env = dict(os.environ)
@@ -524,22 +505,16 @@ def test_bench_smoke_profile_mode(tmp_path):
     line = [ln for ln in proc.stdout.splitlines() if ln.strip()][-1]
     result = json.loads(line)
     assert "error" not in result, result
-
-    bn = result["bottleneck"]
-    head = bn["headline"]
-    assert head["dominant"] in ("dispatch", "settle", "commit", "apply",
-                                "encode", "solve")
-    assert head["costs_seconds"][head["dominant"]] >= 0.0
-    assert abs(sum(head["cost_fractions"].values()) - 1.0) < 0.01
-    assert head["transfer_bytes"]["flush_bytes"] > 0
-    assert head["compile"]["variants"] >= 1
-    assert head["compile"]["compile_seconds_total"] > 0.0
-
-    defrag = bn["defrag"]
-    assert defrag["dominant"] in ("probe_solve", "plan_and_execute")
-    assert defrag["costs_seconds"]["probe_solve"] > 0.0
+    assert "bottleneck" not in result
 
     extras = result["extras"]
+    busy = extras["headline_pipeline_busy_frac"]
+    assert set(busy) == {"dispatch", "settle", "commit", "apply",
+                         "barrier_wait", "apply_wait"}
+    assert all(0.0 <= v <= 1.0 for v in busy.values()), busy
+    assert busy["dispatch"] > 0.0
+    assert extras["defrag_sim_solves"] > 0
+    assert extras["profile_compile_variants"] >= 1
     assert extras["profile_samples"] >= 1
     assert extras["profile_out"] == str(out)
     text = out.read_text()
