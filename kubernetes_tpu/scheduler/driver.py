@@ -45,6 +45,7 @@ from kubernetes_tpu.gang import (
 )
 from kubernetes_tpu.models.policy import DEFAULT_POLICY, Policy
 from kubernetes_tpu.obs import metrics as obs_metrics
+from kubernetes_tpu.obs.collector import install as install_collector_policy
 from kubernetes_tpu.obs.profiling import COMPILES, record_readback
 from kubernetes_tpu.obs.tracing import (
     TRACE_ANNOTATION,
@@ -990,6 +991,8 @@ class Scheduler:
         await self.node_informer.wait_for_sync()
         await self.pod_informer.wait_for_sync()
         await self.podgroup_informer.wait_for_sync()
+        # full collections follow heap growth from here on (once a process)
+        install_collector_policy()
 
     def _flush_events(self) -> None:
         """Record buffered per-batch events — Scheduled bursts plus the
