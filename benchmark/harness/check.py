@@ -3,23 +3,26 @@ reference and hold the timed path's answers against it.
 
 What the scheduler saw when it solved a batch is one prefix of the
 store's history: every bind before the batch's own (earlier batches and
-the batch's earlier rows are in its ledger) and every delete up to the
-point its pod informer had reached at the batch's flush. That point is
-not observable from outside, but it is bracketed: a pod p could only be
-solved after its own ADDED event was applied (the informer applies
-events in order, so every delete before p's creation was in), and only
-before p's bind. So for a sampled pod the reference asks whether ANY
-cut X of the deletes between p's creation and p's bind makes selectHost
-(feasible, best score, round-robin tie in node order with rr = binds
-before p) land on the node the program chose. A sampled pod for which
-no cut does is a mismatch.
+the batch's earlier rows are in its ledger) and every delete its pod
+informer had applied at the batch's flush. The run records that point,
+the watermark, at each flush (drive.Watermarks): the informer's last
+resourceVersion, or the one before the first event parked behind the
+state lock. So for a sampled pod the reference puts back every delete
+past its batch's watermark and asks whether selectHost (feasible, best
+score, round-robin tie in node order with rr = binds before p) lands
+on the node the program chose. The informer sets its resourceVersion
+before its handler runs, so the delete at the watermark itself may not
+have been applied yet: the cut one delete earlier, with that delete put
+back too, is also accepted, and no other. A sampled pod that neither
+cut places on the program's node is a mismatch.
 
-A FailedScheduling verdict ("no node fits") is held the same way. The
-snapshot it was solved on held no pod that the store's history at the
-verdict's event lacks once every delete since the pod's creation is put
-back (binds only ever come earlier, deletes only later), and with the
-mixes' terms more pods can only make a node less feasible. So if some
-node fits the pod in that fullest state, the verdict is wrong.
+A FailedScheduling verdict ("no node fits") is held at the watermark of
+the last batch that left the pod unplaced, at the same two cuts: the
+state it was solved on is the store's history at the verdict's event
+with the deletes past that watermark put back, or with the delete at
+the watermark put back too (binds only ever come earlier). If some node
+fits the pod at both cuts, the verdict is wrong. A sample or verdict
+without a recorded watermark is not checked, and counts as unchecked.
 
 The hard guarantees are checked on every bind of the run, against the
 store's own history (a superset of what any snapshot lacked can only
@@ -34,53 +37,53 @@ import time
 
 import numpy as np
 
-from benchmark.harness.reference import Cluster, interpod_score, select_host
+from benchmark.harness.reference import Cluster
 
 
-def replay_check(nodes, prefill, log, shapes, samples: set,
+def replay_check(nodes, prefill, log, shapes, samples: dict,
                  verdicts=(), deadline_s: float = 120.0) -> dict:
     """nodes: node dicts in creation order; prefill: [(name, node, shape)];
     log: [(rv, code, name, node)] in rv order, code 'A' (pending pod
     created), 'B' (bound to node), 'D' (deleted); shapes: name -> shape
-    of every pod the generator created; samples: names to verify;
-    verdicts: [(rv, name)] FailedScheduling events to verify."""
+    of every pod the generator created; samples: name -> watermark of the
+    batch that bound it (None: not recorded), the binds to verify;
+    verdicts: [(rv, name, watermark)] FailedScheduling events to verify."""
     t0 = time.monotonic()
     ref = Cluster(nodes)
     for name, node, shape in prefill:
         ref.add(name, node, shape)
-    created: dict[str, int] = {}
     dels_rv: list[int] = []
     dels: list[tuple[str, int, object]] = []
     rr = 0
     out = {"decision_mismatch": 0, "verdict_mismatch": 0, "overcommit": 0,
            "anti_affinity": 0, "checked": 0, "verdicts_checked": 0,
-           "cuts": [], "window_deletes": []}
-    merged = sorted([*log, *((rv, "F", name, None) for rv, name in verdicts)]
+           "at_earlier_cut": 0, "past_watermark": []}
+    merged = sorted([*log, *((rv, "F", name, w) for rv, name, w in verdicts)]
                     ) if verdicts else log
-    for rv, code, name, node in merged:
+    for rv, code, name, arg in merged:      # arg: B's node, F's watermark
         if code == "F":
-            lo = bisect.bisect_right(dels_rv, created[name])
-            out["verdict_mismatch"] += _fits_somewhere(ref, shapes[name],
-                                                       dels[lo:])
-            out["verdicts_checked"] += 1
-        elif code == "A":
-            created[name] = rv
+            if arg is not None:
+                lo = bisect.bisect_right(dels_rv, arg)
+                at_w = dels[lo - 1] if lo and dels_rv[lo - 1] == arg else None
+                out["verdict_mismatch"] += _fits_somewhere(ref, shapes[name],
+                                                           dels[lo:], at_w)
+                out["verdicts_checked"] += 1
         elif code == "D":
             j, shape = ref.remove(name)
             dels_rv.append(rv)
             dels.append((name, j, shape))
         elif code == "B":
-            shape = shapes[name]
+            shape, node = shapes[name], arg
             j = ref.index[node]
-            if name in samples:
-                lo = bisect.bisect_right(dels_rv, created[name])
-                steps = _any_cut(ref, shape, j, rr, dels[lo:])
+            w = samples.get(name)
+            if w is not None:
+                lo = bisect.bisect_right(dels_rv, w)
+                at_w = dels[lo - 1] if lo and dels_rv[lo - 1] == w else None
+                cut = _pinned_cut(ref, shape, j, rr, dels[lo:], at_w)
                 out["checked"] += 1
-                out["window_deletes"].append(len(dels) - lo)
-                if steps is None:
-                    out["decision_mismatch"] += 1
-                else:
-                    out["cuts"].append(steps)
+                out["past_watermark"].append(len(dels) - lo)
+                out["decision_mismatch"] += cut is None
+                out["at_earlier_cut"] += cut == "earlier"
                 if time.monotonic() - t0 > deadline_s:
                     raise TimeoutError("reference check over its deadline")
             if not ref.interpod_ok(j, shape):
@@ -95,52 +98,51 @@ def replay_check(nodes, prefill, log, shapes, samples: set,
     return out
 
 
-def _fits_somewhere(ref: Cluster, shape, recent) -> bool:
-    """Whether some node fits `shape` with the deletes `recent` put back:
-    the fullest state any snapshot behind the verdict could have held."""
-    for name, j, s in recent:
+def _fits_somewhere(ref: Cluster, shape, past, at_w) -> bool:
+    """Whether some node fits `shape` at the watermark (the deletes `past`
+    put back) and, where there is a delete `at_w` at the watermark, with
+    it put back too: at both cuts the snapshot behind the verdict could
+    have been. Restores `ref`."""
+    back = list(past)
+    for name, j, s in back:
         ref.add(name, ref.names[j], s)
-    feas, _ = ref.node_terms(shape, slice(None))
-    ok, _ = ref.domain_terms(shape)
-    for name, _, _ in recent:
-        ref.remove(name)
-    return bool((feas & ok).any())
-
-
-def _any_cut(ref: Cluster, shape, want: int, rr: int, recent) -> int | None:
-    """Whether some cut of `recent` (the deletes between the pod's
-    creation and its bind, oldest first; all applied in `ref` on entry)
-    makes selectHost pick `want`. Tries all applied, then none, then
-    applies them one at a time; restores `ref`. Returns the cut (number
-    of recent deletes applied) or None. Each step rescores the deleted
-    pod's node, and the zone-level terms only where that pod can reach
-    `shape` beyond its own node."""
-    def score():
-        f = feas & ok
-        return select_host(f, base + interpod_score(count, f), rr)
-
-    feas, base = ref.node_terms(shape, slice(None))
-    ok, count = ref.domain_terms(shape)
-    if score() == want:
-        return len(recent)
-    for name, j, s in reversed(recent):
-        ref.add(name, ref.names[j], s)
-    found = None
-    feas, base = ref.node_terms(shape, slice(None))
-    ok, count = ref.domain_terms(shape)
-    for cut, (name, j, s) in enumerate(recent):
-        if score() == want:
-            found = cut
-            break
-        ref.remove(name)
-        fj, bj = ref.node_terms(shape, [j])
-        feas[j], base[j] = fj[0], bj[0]
-        if ref.touches(s, shape):
-            ok, count = ref.domain_terms(shape)
-    if found is not None:
-        for name, _, _ in recent[found:]:
+    try:
+        for extra in [None] + ([at_w] if at_w else []):
+            if extra:
+                name, j, s = extra
+                ref.add(name, ref.names[j], s)
+                back.append(extra)
+            feas, _ = ref.node_terms(shape)
+            ok, _ = ref.domain_terms(shape)
+            if not (feas & ok).any():
+                return False
+        return True
+    finally:
+        for name, _, _ in back:
             ref.remove(name)
-    return found
+
+
+def _pinned_cut(ref: Cluster, shape, want: int, rr: int, past,
+                at_w) -> str | None:
+    """Whether selectHost picks `want` at the watermark: with the deletes
+    `past` (those after it; all applied in `ref` on entry) put back, or
+    else with the delete `at_w` (the one at the watermark, or None) put
+    back too. Returns "watermark", "earlier" or None; restores `ref`."""
+    back = list(past)
+    for name, j, s in back:
+        ref.add(name, ref.names[j], s)
+    try:
+        if ref.pick(shape, rr) == want:
+            return "watermark"
+        if at_w is None:
+            return None
+        name, j, s = at_w
+        ref.add(name, ref.names[j], s)
+        back.append(at_w)
+        return "earlier" if ref.pick(shape, rr) == want else None
+    finally:
+        for name, _, _ in back:
+            ref.remove(name)
 
 
 def sample_names(candidates: list, k: int, seed: int,

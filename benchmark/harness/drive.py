@@ -16,6 +16,7 @@ import gc
 import math
 import statistics
 import sys
+import threading
 import time
 import traceback
 from collections import deque
@@ -114,6 +115,63 @@ def warm_row_scatter(sched) -> int:
             jax.block_until_ready(db._scatter_rows(dev, list(range(k))))
             k *= 2
     return k.bit_length() - 1
+
+
+class Watermarks:
+    """For each solved batch, the watermark of its StateDB flush -- the
+    resourceVersion up to which the scheduler's pod informer had applied
+    the store's pod events to the state the batch was solved on -- with
+    the batch's pods and rows, in the order the batches were bound.
+
+    The flush runs under the scheduler's state lock, so no informer
+    handler changes the state while the watermark is read there: it is
+    the informer's last resourceVersion, or, where events wait parked
+    behind the lock (FIFO: once one parks, every later one does), the one
+    before the first of them. The flush is paired with its batch through
+    the result the same thread's adopt_result takes, and that result with
+    the batch's pods at its _apply_batch (the bind), so a failed solve or
+    an out-of-order stage cannot shift the pairing. One tuple per batch
+    on the timed path. Every batch from the scheduler's start is kept: a
+    batch flushed in the warm-up can bind in the window; the close's
+    ledger read-back flushes with no batch and pairs with nothing."""
+
+    def __init__(self, sched):
+        self.batches: list[tuple[int | None, list, list]] = []
+        local = threading.local()
+        solved: dict[int, tuple[int | None, object]] = {}
+        db, informer = sched.statedb, sched.pod_informer
+        parked = sched._deferred_events
+        flush, adopt, apply = db.flush, db.adopt_result, sched._apply_batch
+
+        def flushed():
+            state = flush()
+            local.wm = (parked[0][1].resource_version - 1 if parked
+                        else informer._last_rv)
+            return state
+
+        def adopted(result):
+            adopt(result)
+            solved[id(result)] = (getattr(local, "wm", None), result)
+
+        def applied(result, pods, live_keys, blobs, flags, rows, *a, **kw):
+            wm, _ = solved.pop(id(result), (None, None))
+            self.batches.append((wm, live_keys, rows))
+            return apply(result, pods, live_keys, blobs, flags, rows, *a,
+                         **kw)
+
+        db.flush, db.adopt_result = flushed, adopted
+        sched._apply_batch = applied
+
+    def pins(self) -> tuple[dict, dict]:
+        """(pod name -> watermark of the last batch that placed it, pod
+        name -> watermark of the last batch that left it unplaced)."""
+        placed: dict = {}
+        unplaced: dict = {}
+        for wm, keys, rows in self.batches:
+            for key, row in zip(keys, rows):
+                (placed if row >= 0 else unplaced)[key.rpartition("/")[2]] \
+                    = wm
+        return placed, unplaced
 
 
 def percentile(values: list[float], q: float) -> float:
@@ -345,6 +403,7 @@ class Run:
             num_nodes=cfg["capacities"]["num_nodes"], batch_pods=self.batch))
         if self.fault is not None:
             self.fault(self.sched)
+        self.watermarks = Watermarks(self.sched)
         await self.sched.start()
         marks["informers_synced"] = time.monotonic() - self.t_process
         tasks = [loop.create_task(self._watch()),
@@ -508,23 +567,29 @@ def run_cell(cfg, traffic, seed, seconds, trace_dir, t_process,
     # correctness, once the program's state is gone
     longest = max(sample_from, key=lambda n: run.bound_at[n]
                   - run.created_at[n]) if sample_from else None
-    samples = sample_names(sample_from, traffic["check_sample"], seed,
-                           [longest] if longest else [])
-    verdicts = sample_names(got["verdicts"], traffic["check_sample"] // 2,
-                            seed)
+    placed, unplaced = run.watermarks.pins()
+    samples = {n: placed.get(n) for n in sample_names(
+        sample_from, traffic["check_sample"], seed,
+        [longest] if longest else [])}
+    verdicts = [(rv, n, unplaced.get(n)) for rv, n in sample_names(
+        got["verdicts"], traffic["check_sample"] // 2, seed)]
     checked = replay_check(node_dicts(cfg), run.prefill, run.log, run.shapes,
                            samples, verdicts)
     log(f"reference: {checked['checked']} sampled decisions in "
-        f"{checked['seconds']:.3f} s; deletes in flight per sample median "
-        f"{statistics.median(checked['window_deletes'] or [0])}, of them "
-        f"applied at the matching cut: median "
-        f"{statistics.median(checked['cuts'] or [0])}; "
-        f"{checked['verdicts_checked']} of {len(got['verdicts'])} "
-        f"FailedScheduling verdicts in the window checked")
+        f"{checked['seconds']:.3f} s at their batches' watermarks "
+        f"({len(run.watermarks.batches)} batches recorded); deletes past "
+        f"the watermark per sample median "
+        f"{statistics.median(checked['past_watermark'] or [0])}; "
+        f"{checked['at_earlier_cut']} matched only at the cut one delete "
+        f"earlier; {checked['verdicts_checked']} of "
+        f"{len(got['verdicts'])} FailedScheduling verdicts in the window "
+        f"checked")
     checks = {
         "decision_mismatch": (checked["decision_mismatch"], 0),
         "verdict_mismatch": (checked["verdict_mismatch"], 0),
-        "unchecked_samples": (len(samples) - checked["checked"], 0),
+        "unchecked_samples": (len(samples) + len(verdicts)
+                              - checked["checked"]
+                              - checked["verdicts_checked"], 0),
         "rebinds": (run.rebinds, 0),
         "overcommit_binds": (checked["overcommit"], 0),
         "anti_affinity_breaks": (checked["anti_affinity"], 0),

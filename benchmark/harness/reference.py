@@ -5,8 +5,10 @@ Independent of the program: it reads the v1-style dicts the traffic
 generator made (never the program's objects, encodings or tables) and
 reimplements what `tests/serial_reference.py` specifies for the terms
 the benchmark's mixes use -- the resource fit (pods, cpu, memory),
-required pod anti-affinity, preferred pod affinity (both directions),
-LeastRequested, BalancedResourceAllocation and InterPodAffinity
+required pod affinity and anti-affinity, preferred pod affinity and
+anti-affinity (both directions, anti-affinity's weight subtracted), the
+symmetric weight of a resident's required affinity term, LeastRequested,
+BalancedResourceAllocation and InterPodAffinity
 priorities, and selectHost's round-robin tie-break in node-list order.
 Every other priority of the default policy is a constant over identical
 nodes (no taints, no node affinity, no services, no avoid annotations)
@@ -31,6 +33,7 @@ ZONE = "failure-domain.beta.kubernetes.io/zone"
 TOPOLOGIES = (HOST, ZONE)
 DEFAULT_NONZERO_CPU = 100                 # milli (non_zero.go)
 DEFAULT_NONZERO_MEM = 200 * 1024 * 1024   # bytes
+HARD_WEIGHT = 1     # HardPodAffinitySymmetricWeight, the v1.8 default
 
 _SUFFIX = {"": 1, "m": Fraction(1, 1000), "k": 10**3, "M": 10**6,
            "G": 10**9, "T": 10**12, "Ki": 2**10, "Mi": 2**20,
@@ -56,7 +59,8 @@ def _units(q) -> int:
 
 @dataclass(frozen=True)
 class Term:
-    """One pod (anti-)affinity term: a matchLabels selector in a topology."""
+    """One pod (anti-)affinity term: a matchLabels selector in a topology.
+    A preferred term carries its signed weight: + affinity, - anti-affinity."""
     selector: tuple          # sorted ((key, value), ...)
     topology: str
     weight: int = 0
@@ -74,7 +78,8 @@ class PodShape:
     nz_mem: int
     labels: tuple            # sorted ((key, value), ...)
     anti_req: tuple = ()     # Terms
-    aff_pref: tuple = ()     # Terms with weight
+    pref: tuple = ()         # preferred Terms, signed weight
+    aff_req: tuple = ()      # required affinity Terms
 
     @property
     def label_dict(self) -> dict:
@@ -112,17 +117,17 @@ def pod_shape(pod: dict) -> PodShape:
     if aff.get("nodeAffinity"):
         raise NotImplementedError("node affinity is outside the reference")
     pa, anti = aff.get("podAffinity") or {}, aff.get("podAntiAffinity") or {}
-    if pa.get("requiredDuringSchedulingIgnoredDuringExecution") or \
-            anti.get("preferredDuringSchedulingIgnoredDuringExecution"):
-        raise NotImplementedError("required affinity / preferred anti-affinity")
-    anti_req = tuple(_term(t) for t in
-                     anti.get("requiredDuringSchedulingIgnoredDuringExecution")
-                     or ())
-    prefs = pa.get("preferredDuringSchedulingIgnoredDuringExecution") or ()
-    aff_pref = tuple(_term(t["podAffinityTerm"], int(t["weight"]))
-                     for t in prefs if int(t["weight"]))
+    anti_req, aff_req = (tuple(_term(t) for t in terms.get(
+        "requiredDuringSchedulingIgnoredDuringExecution") or ())
+        for terms in (anti, pa))
+    pref = tuple(_term(t["podAffinityTerm"], sign * int(t["weight"]))
+                 for sign, terms in ((1, pa), (-1, anti))
+                 for t in terms.get(
+                     "preferredDuringSchedulingIgnoredDuringExecution") or ()
+                 if int(t["weight"]))
     labels = tuple(sorted(((pod["metadata"].get("labels")) or {}).items()))
-    return PodShape(cpu, mem, nz_cpu, nz_mem, labels, anti_req, aff_pref)
+    return PodShape(cpu, mem, nz_cpu, nz_mem, labels, anti_req, pref,
+                    aff_req)
 
 
 def node_alloc(node: dict) -> tuple[int, int, int]:
@@ -182,17 +187,25 @@ def serial_pick(nodes: list[dict], placed: list[tuple[dict, int]],
                 if t.matches(s.label_dict) and \
                         topo[i][t.topology] == topo[j][t.topology]:
                     ok = False
+        for t in me.aff_req:      # the pod's own required affinity
+            hits = [i for s, i in shapes if t.matches(s.label_dict)]
+            if not any(topo[i][t.topology] == topo[j][t.topology]
+                       for i in hits) and (hits or not t.matches(mine)):
+                ok = False        # only the first pod matching itself escapes
         if not ok:
             continue
         count = 0
         for s, i in shapes:
             same = {k: topo[i][k] == topo[j][k] for k in TOPOLOGIES}
-            for t in me.aff_pref:
+            for t in me.pref:
                 if t.matches(s.label_dict) and same[t.topology]:
                     count += t.weight
-            for t in s.aff_pref:
+            for t in s.pref:
                 if t.matches(mine) and same[t.topology]:
                     count += t.weight
+            for t in s.aff_req:
+                if t.matches(mine) and same[t.topology]:
+                    count += HARD_WEIGHT
         fits.append(j)
         counts.append(count)
     if not fits:
@@ -244,11 +257,11 @@ class Cluster:
         self._sels: dict = {}
         self.sig_node = np.zeros((n, 0), np.int64)
         self.sig_zone = np.zeros((self.n_zones, 0), np.int64)
-        # resident terms: anti_req count and aff_pref weight, per topology
-        self.anti = {HOST: np.zeros((n, 0), np.int64),
-                     ZONE: np.zeros((self.n_zones, 0), np.int64)}
-        self.pref = {HOST: np.zeros((n, 0), np.int64),
-                     ZONE: np.zeros((self.n_zones, 0), np.int64)}
+        # resident terms: anti_req and aff_req counts and signed pref
+        # weight, per topology
+        self.anti, self.aff, self.pref = (
+            {HOST: np.zeros((n, 0), np.int64),
+             ZONE: np.zeros((self.n_zones, 0), np.int64)} for _ in range(3))
         self.where: dict[str, tuple[int, PodShape]] = {}
         self._match = np.zeros((0, 0), bool)   # [sig, sel]
 
@@ -263,7 +276,7 @@ class Cluster:
     def _sel(self, selector: tuple) -> int:
         if selector not in self._sels:
             self._sels[selector] = len(self._sels)
-            for table in (self.anti, self.pref):
+            for table in (self.anti, self.aff, self.pref):
                 for k in table:
                     table[k] = np.pad(table[k], ((0, 0), (0, 1)))
             self._rebuild_match()
@@ -277,14 +290,6 @@ class Cluster:
                 m[i, k] = all(d.get(a) == b for a, b in sel)
         self._match = m
 
-    def touches(self, q: PodShape, s: PodShape) -> bool:
-        """Whether adding or removing pod shape q can change anything of
-        pod shape s beyond q's own node's `node_terms`."""
-        if q.anti_req or q.aff_pref:
-            return True
-        lab = q.label_dict
-        return any(t.matches(lab) for t in s.anti_req + s.aff_pref)
-
     def _domain(self, topo: str, j: int) -> int:
         return j if topo == HOST else int(self.zone_of[j])
 
@@ -297,10 +302,11 @@ class Cluster:
         g = self._sig(s.labels)
         self.sig_node[j, g] += sign
         self.sig_zone[self.zone_of[j], g] += sign
-        for t in s.anti_req:
-            k = self._sel(t.selector)      # may widen the tables first
-            self.anti[t.topology][self._domain(t.topology, j), k] += sign
-        for t in s.aff_pref:
+        for table, terms in ((self.anti, s.anti_req), (self.aff, s.aff_req)):
+            for t in terms:
+                k = self._sel(t.selector)      # may widen the tables first
+                table[t.topology][self._domain(t.topology, j), k] += sign
+        for t in s.pref:
             k = self._sel(t.selector)
             self.pref[t.topology][self._domain(t.topology, j), k] += \
                 sign * t.weight
@@ -321,25 +327,25 @@ class Cluster:
         per = (self.sig_node if topo == HOST else self.sig_zone) @ col
         return per if topo == HOST else per[self.zone_of]
 
-    def node_terms(self, s: PodShape, idx) -> tuple[np.ndarray, np.ndarray]:
-        """Node-local part for nodes `idx`: (feasible by resources and by
+    def node_terms(self, s: PodShape) -> tuple[np.ndarray, np.ndarray]:
+        """Node-local part on every node: (feasible by resources and by
         hostname anti-affinity both ways, LeastRequested + Balanced)."""
-        for t in s.anti_req + s.aff_pref:
+        for t in s.anti_req + s.pref + s.aff_req:
             self._sel(t.selector)
         g = self._sig(s.labels)       # may widen the tables first
         mine = self._match[g]            # selectors matching s
-        feas = self.pods[idx] + 1 <= self.cap_pods[idx]
-        cc, cm = self.cap_cpu[idx], self.cap_mem[idx]
+        feas = self.pods + 1 <= self.cap_pods
+        cc, cm = self.cap_cpu, self.cap_mem
         if s.cpu or s.mem:
-            feas &= (self.cpu[idx] + s.cpu <= cc) & (self.mem[idx] + s.mem <= cm)
+            feas &= (self.cpu + s.cpu <= cc) & (self.mem + s.mem <= cm)
         if mine.any():
-            feas &= ~(self.anti[HOST][idx][:, mine].sum(1) > 0)
+            feas &= ~(self.anti[HOST][:, mine].sum(1) > 0)
         for t in s.anti_req:
             if t.topology == HOST:
                 k = self._sel(t.selector)
                 col = self._match[:, k]
-                feas &= ~(self.sig_node[idx][:, col].sum(1) > 0)
-        nzc, nzm = self.nzc[idx] + s.nz_cpu, self.nzm[idx] + s.nz_mem
+                feas &= ~(self.sig_node[:, col].sum(1) > 0)
+        nzc, nzm = self.nzc + s.nz_cpu, self.nzm + s.nz_mem
         lr_c = np.where((cc == 0) | (nzc > cc), 0,
                         ((cc - nzc) * MAX_PRIORITY) // np.maximum(cc, 1))
         lr_m = np.where((cm == 0) | (nzm > cm), 0,
@@ -352,9 +358,13 @@ class Cluster:
         return feas, (lr_c + lr_m) // 2 + bal
 
     def domain_terms(self, s: PodShape) -> tuple[np.ndarray, np.ndarray]:
-        """Zone-level feasibility [N] and the InterPodAffinity weighted
-        count [N] (own preferred terms, residents' preferred terms)."""
-        g = self._sig(s.labels)
+        """Zone-level feasibility and the pod's own required affinity [N],
+        and the InterPodAffinity weighted count [N] (own preferred terms,
+        residents' preferred terms and, at HARD_WEIGHT, their required
+        affinity terms)."""
+        for t in s.anti_req + s.pref + s.aff_req:
+            self._sel(t.selector)
+        g = self._sig(s.labels)       # may widen the tables first
         mine = self._match[g]
         ok = np.ones(len(self.names), bool)
         if mine.any():
@@ -362,18 +372,28 @@ class Cluster:
         for t in s.anti_req:
             if t.topology == ZONE:
                 ok &= ~(self._count_matching(ZONE, self._sel(t.selector)) > 0)
+        for t in s.aff_req:
+            k = self._sel(t.selector)
+            col = self._match[:, k]
+            if self.sig_zone[:, col].sum() > 0:
+                ok &= self._count_matching(t.topology, k) > 0
+            elif not mine[k]:     # none anywhere, and not the first of them
+                ok[:] = False
         count = np.zeros(len(self.names), np.int64)
-        for t in s.aff_pref:
+        for t in s.pref:
             count += t.weight * self._count_matching(t.topology,
                                                      self._sel(t.selector))
         if mine.any():
             count += self.pref[HOST][:, mine].sum(1)
             count += self.pref[ZONE][:, mine].sum(1)[self.zone_of]
+            count += HARD_WEIGHT * self.aff[HOST][:, mine].sum(1)
+            count += HARD_WEIGHT * \
+                self.aff[ZONE][:, mine].sum(1)[self.zone_of]
         return ok, count
 
     def evaluate(self, s: PodShape) -> tuple[np.ndarray, np.ndarray]:
         """(feasible[N], score[N]) of pod shape `s` on every node."""
-        feas, base = self.node_terms(s, slice(None))
+        feas, base = self.node_terms(s)
         ok, count = self.domain_terms(s)
         feas &= ok
         return feas, base + interpod_score(count, feas)

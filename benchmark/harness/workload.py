@@ -7,7 +7,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from benchmark.harness.reference import pod_shape
+from benchmark.harness.reference import HOST, ZONE, pod_shape
 
 
 def node_dicts(cfg: dict) -> list[dict]:
@@ -26,35 +26,57 @@ def node_dicts(cfg: dict) -> list[dict]:
     } for i in range(cfg["nodes"])]
 
 
+TOPOLOGY = {"hostname": HOST, "zone": ZONE}
+
+
+def _term(service: int, term: dict) -> dict:
+    return {"labelSelector": {"matchLabels": {
+                "app": f"{term['app']}-{service}"}},
+            "topologyKey": TOPOLOGY[term["topology"]]}
+
+
+def _preferred(service: int, terms: list) -> list:
+    return [{"weight": t["weight"], "podAffinityTerm": _term(service, t)}
+            for t in terms]
+
+
 def pod_dict(name: str, i: int, mix: dict) -> dict:
-    """make_pods' template for pattern index i: app=app-{i % groups},
-    required hostname anti-affinity to the own group on every
-    `anti_affinity_every`-th, preferred zone affinity (weight 10) to it
-    on every `pref_affinity_every`-th."""
-    groups = mix.get("app_groups", 0)
+    """make_pods' template for pattern index i. A mix with `roles` runs
+    `services` copies of them: pattern i is role i % len(roles) of service
+    i // len(roles), labelled app=<role app>-<service>, its terms selecting
+    app=<term app>-<service> of its own service (`affinity_required` /
+    `anti_required`: required pod affinity / anti-affinity;
+    `affinity_preferred` / `anti_preferred`: preferred pod affinity /
+    anti-affinity with their weights), in `topology` hostname or zone. A
+    mix without roles is one plain pod."""
     meta: dict = {"name": name, "namespace": "default"}
     spec: dict = {"containers": [{
         "name": "app", "image": "k8s.gcr.io/pause:3.0",
         "resources": {"requests": {"cpu": mix["cpu"],
                                    "memory": mix["memory"]}}}]}
-    if groups:
-        meta["labels"] = {"app": f"app-{i % groups}"}
-        sel = {"matchLabels": {"app": f"app-{i % groups}"}}
+    roles = mix.get("roles")
+    if roles:
+        role, service = roles[i % len(roles)], i // len(roles)
+        meta["labels"] = {"app": f"{role['app']}-{service}"}
+        anti: dict = {}
+        if role.get("anti_required"):
+            anti["requiredDuringSchedulingIgnoredDuringExecution"] = [
+                _term(service, t) for t in role["anti_required"]]
+        if role.get("anti_preferred"):
+            anti["preferredDuringSchedulingIgnoredDuringExecution"] = \
+                _preferred(service, role["anti_preferred"])
+        pod_aff: dict = {}
+        if role.get("affinity_required"):
+            pod_aff["requiredDuringSchedulingIgnoredDuringExecution"] = [
+                _term(service, t) for t in role["affinity_required"]]
+        if role.get("affinity_preferred"):
+            pod_aff["preferredDuringSchedulingIgnoredDuringExecution"] = \
+                _preferred(service, role["affinity_preferred"])
         affinity: dict = {}
-        every = mix.get("anti_affinity_every", 0)
-        if every and i % every == 0:
-            affinity["podAntiAffinity"] = {
-                "requiredDuringSchedulingIgnoredDuringExecution": [{
-                    "labelSelector": sel,
-                    "topologyKey": "kubernetes.io/hostname"}]}
-        every = mix.get("pref_affinity_every", 0)
-        if every and i % every == 0:
-            affinity["podAffinity"] = {
-                "preferredDuringSchedulingIgnoredDuringExecution": [{
-                    "weight": 10, "podAffinityTerm": {
-                        "labelSelector": sel,
-                        "topologyKey":
-                            "failure-domain.beta.kubernetes.io/zone"}}]}
+        if pod_aff:
+            affinity["podAffinity"] = pod_aff
+        if anti:
+            affinity["podAntiAffinity"] = anti
         if affinity:
             spec["affinity"] = affinity
     return {"metadata": meta, "spec": spec}
@@ -69,9 +91,7 @@ class PodSequence:
     def __init__(self, mix: dict, seed: int, prefix: str):
         self.mix = mix
         self.prefix = prefix
-        self.period = int(np.lcm.reduce([
-            max(1, mix.get(k, 0) or 1) for k in
-            ("app_groups", "anti_affinity_every", "pref_affinity_every")]))
+        self.period = mix.get("services", 1) * len(mix.get("roles") or [1])
         self.rng = np.random.default_rng([seed, len(prefix)])
         self.block: list[int] = []
         self.n = 0
@@ -116,33 +136,64 @@ def prefill(nodes: list[dict], per_node: int, seq: PodSequence,
             seed: int) -> list[tuple[dict, str]]:
     """Resident pods, pre-bound: `per_node` on every node, placed by a
     seeded round-robin that skips a node where the pod would break a
-    required hostname anti-affinity term, its own or a resident's (the
-    guarantees hold from the first moment). Returns [(name, pattern,
-    node name)] oldest first -- the churn's delete order."""
+    required hostname anti-affinity term, its own or a resident's, or its
+    own required affinity term (the guarantees hold from the first
+    moment). A pod whose affinity no resident can meet yet waits until
+    one can. Returns [(name, pattern, node name)] oldest first -- the
+    churn's delete order."""
     n = len(nodes)
+    zone = [nd["metadata"]["labels"][ZONE] for nd in nodes]
     order = np.random.default_rng([seed, 7]).permutation(n)
     count = [0] * n
     labels_on: list[set] = [set() for _ in range(n)]
     terms_on: list[set] = [set() for _ in range(n)]
-    out = []
+    labels_in = {z: set() for z in zone}
+    out: list = []
     cursor = 0
-    for _ in range(per_node * n):
-        name, i = seq.next()
+
+    def affinity_met(t, s, j) -> bool:
+        here = labels_on[j] if t.topology == HOST else labels_in[zone[j]]
+        if any(t.matches(dict(lab)) for lab in here):
+            return True
+        return not any(t.matches(dict(lab)) for z in labels_in.values()
+                       for lab in z) and t.matches(s.label_dict)
+
+    def place(name, i) -> bool:
+        nonlocal cursor
         s = seq.shape(i)
-        if any(t.topology != "kubernetes.io/hostname" for t in s.anti_req):
-            raise NotImplementedError("prefill places hostname terms only")
         mine = s.label_dict
         for step in range(n):
             j = int(order[(cursor + step) % n])
             if count[j] >= per_node or any(t.matches(mine)
                                            for t in terms_on[j]) or any(
                     t.matches(dict(lab)) for t in s.anti_req
-                    for lab in labels_on[j]):
+                    for lab in labels_on[j]) or not all(
+                    affinity_met(t, s, j) for t in s.aff_req):
                 continue
             cursor = (cursor + step + 1) % n
             count[j] += 1
             labels_on[j].add(s.labels)
+            labels_in[zone[j]].add(s.labels)
             terms_on[j].update(s.anti_req)
             out.append((name, i, nodes[j]["metadata"]["name"]))
-            break
+            return True
+        return False
+
+    waiting: list = []
+    while len(out) + len(waiting) < per_node * n:
+        name, i = seq.next()
+        if any(t.topology != HOST for t in seq.shape(i).anti_req):
+            raise NotImplementedError("prefill places hostname terms only")
+        if not place(name, i):
+            waiting.append((name, i))
+            continue
+        progress = True
+        while waiting and progress:
+            progress = False
+            for w in list(waiting):
+                if place(*w):
+                    waiting.remove(w)
+                    progress = True
+    if waiting:
+        raise ValueError(f"prefill could not place {len(waiting)} pods")
     return out
