@@ -13,6 +13,10 @@ on earlier lines; the last line of standard output is exactly
 One chip, at the bench.py headline size (15,000 nodes in 3 zones, 30,000
 pending pods; capacities N=16,384, P=4,096 from run_throughput's defaults):
 
+0. upload: a host array put through StateDB's upload helper
+   (`statedb.put_owned`) and mutated at once reads unchanged on the device,
+   at four ledger shapes; a raw `jax.device_put` of the same array is
+   counted beside it, the witness that the device reads its source late.
 1. headline: `perf.harness.run_throughput` (Scheduler + staged pipeline over
    an in-memory ObjectStore); afterwards, from the store, every pod is bound
    exactly once, with zero binding errors and no node overcommitted.
@@ -68,6 +72,36 @@ def check(ok: bool, what: str) -> None:
 def pow2_nodes(n_nodes: int) -> int:
     """run_throughput's node capacity for n_nodes."""
     return 1 << max(6, (n_nodes - 1).bit_length())
+
+
+# ---- phase 0: host->device uploads are snapshots --------------------
+
+# StateDB ledger shapes: (N, W) f32 at the cells' node capacities
+UPLOAD_SHAPES = ((8192, 8), (8192, 64), (16384, 16), (8192, 512))
+
+
+def upload_probe(device, tries: int = 200) -> None:
+    import jax
+
+    from kubernetes_tpu.state.statedb import put_owned
+
+    puts = {"device_put": lambda a: jax.device_put(a, device),
+            "put_owned": lambda a: put_owned(a, device)}
+    for shape in UPLOAD_SHAPES:
+        seen = {}
+        for name, put in puts.items():
+            seen[name] = 0
+            for _ in range(tries):
+                src = np.zeros(shape, np.float32)
+                dev = put(src)
+                src += 1.0   # as an informer handler, once the lock is free
+                seen[name] += bool(np.asarray(dev).any())
+        say(f"upload {shape}: the mutation reached the device in "
+            f"{seen['device_put']} of {tries} raw device_puts, "
+            f"{seen['put_owned']} of {tries} put_owned")
+        check(seen["put_owned"] == 0,
+              f"upload {shape}: put_owned let {seen['put_owned']} of "
+              f"{tries} later mutations reach the device")
 
 
 # ---- phase 1: headline end to end -------------------------------------
@@ -435,6 +469,7 @@ def one_chip(chip) -> None:
 
     cpu = jax.devices("cpu")[0]
     caps = Capacities(num_nodes=pow2_nodes(HEADLINE_NODES), batch_pods=4096)
+    upload_probe(chip)
     headline(HEADLINE_NODES, HEADLINE_PODS)
 
     enc = Encoded(HEADLINE_NODES, caps, {}, n_bound=HEADLINE_PODS)

@@ -41,6 +41,7 @@ With no profiler capture open a TraceMe costs well under a microsecond.
 
 from __future__ import annotations
 
+import contextvars
 import gc
 import json
 import os
@@ -346,6 +347,12 @@ def _trace_me():
     return _TRACE_ME
 
 
+# the metrics of the innermost open stage (per thread, and per asyncio
+# task, so a stage held open across an await is not seen by other tasks)
+_STAGE_METRICS: contextvars.ContextVar = contextvars.ContextVar(
+    "ktpu_stage_metrics", default=None)
+
+
 class stage:
     """One stage boundary: ``with stage(name, ...) as st: <work>``.
 
@@ -354,7 +361,10 @@ class stage:
       carries one id);
     - on exit adds the elapsed time to ``metrics.add_phase(phase, ...)``,
       in thread CPU time where ``cpu`` (GIL waits on concurrent stage
-      threads excluded), wall time otherwise;
+      threads excluded), wall time otherwise; a stage given no ``metrics``
+      reports to the innermost enclosing stage's in this context, and
+      records no phase outside one (code below the scheduler, such as
+      StateDB, names its sub-stages without holding a metrics handle);
     - adds the wall time to ``busy[0][busy[1]]`` (a pipeline busy total);
     - records a retroactive Dapper child named ``tid`` on that row when
       ``work_span`` is sampled (settable inside the block, with
@@ -365,7 +375,7 @@ class stage:
 
     __slots__ = ("name", "batch", "metrics", "phase", "cpu", "busy",
                  "work_span", "tid", "attrs", "status", "elapsed", "_tm",
-                 "_t0", "_c0")
+                 "_t0", "_c0", "_token")
 
     def __init__(self, name: str, *, batch: int | None = None,
                  metrics=None, phase: str | None = None, cpu: bool = False,
@@ -384,6 +394,9 @@ class stage:
         self.elapsed = 0.0
 
     def __enter__(self) -> "stage":
+        if self.metrics is None:
+            self.metrics = _STAGE_METRICS.get()
+        self._token = _STAGE_METRICS.set(self.metrics)
         tm = _trace_me()
         self._tm = tm(self.name) if self.batch is None \
             else tm(self.name, batch=self.batch)
@@ -397,8 +410,9 @@ class stage:
         wall = time.perf_counter() - self._t0
         cpu = time.thread_time() - self._c0 if self.cpu else wall
         self._tm.__exit__(exc_type, exc, tb)
+        _STAGE_METRICS.reset(self._token)
         self.elapsed = wall
-        if self.phase is not None:
+        if self.phase is not None and self.metrics is not None:
             self.metrics.add_phase(self.phase, cpu)
         if self.busy is not None:
             totals, key = self.busy

@@ -90,8 +90,11 @@ def pad_state(state: ClusterState, mesh: Mesh) -> ClusterState:
 
 
 def shard_state(state: ClusterState, mesh: Mesh) -> ClusterState:
-    state = pad_state(state, mesh)
-    return jax.device_put(state, state_sharding(mesh))
+    """Node-shard a host state onto the mesh, through an owned copy
+    (`statedb.put_owned`): the caller may go on mutating its arrays."""
+    from kubernetes_tpu.state.statedb import put_owned
+
+    return put_owned(pad_state(state, mesh), state_sharding(mesh))
 
 
 def shard_batch(batch: PodBatch, mesh: Mesh) -> PodBatch:

@@ -377,30 +377,48 @@ async def _run_soak(tape: Tape, *, tick_seconds: float,
     # the memory/latency baselines start. A variant first seen mid-day
     # would read as a ~100ms+ compile stall the control plane never
     # caused.
-    warm_names: list[str] = []
-    warm_units = [("soak-warm0", 1, 0), ("soak-warmp", 1, 1000),
-                  ("soak-warmg", 2, 0), ("soak-warmgp", 2, 1000)]
-    for base, width, prio in warm_units:
+    async def warm(base: str, width: int, prio: int) -> list[str]:
         if width == 1:
             ev = Event(0, SUBMIT, base, cpu_m=100, mem_mi=100,
                        priority=prio)
             inner.create(Pod.from_dict(_pod_dict(f"{base}-0", ev)))
-            warm_names.append(f"{base}-0")
+            names = [f"{base}-0"]
         else:
             ev = Event(0, SUBMIT_GANG, base, cpu_m=100, mem_mi=100,
                        width=width, priority=prio)
-            for k in range(width):
-                inner.create(Pod.from_dict(
-                    _pod_dict(f"{base}-{k}", ev, gang=base)))
-                warm_names.append(f"{base}-{k}")
+            names = [f"{base}-{k}" for k in range(width)]
+            for nm in names:
+                inner.create(Pod.from_dict(_pod_dict(nm, ev, gang=base)))
         async with asyncio.timeout(converge_timeout_s):
             while unconverged():
                 await asyncio.sleep(0.02)
-    for nm in warm_names:
-        try:
-            inner.delete("Pod", nm, "default")
-        except NotFound:
-            pass
+        return names
+
+    def delete_pods(names: list[str]) -> None:
+        for nm in names:
+            try:
+                inner.delete("Pod", nm, "default")
+            except NotFound:
+                pass
+
+    # a preempt variant traces twice: with a victim table, and without
+    # one when no evictable pod is accounted anywhere -- the empty
+    # cluster the day starts on once the warm pods below are deleted.
+    # Warm that shape first: each preempt unit alone on an empty
+    # cluster, deleted and forgotten by the scheduler before the next
+    for base, width in (("soak-warmpe", 1), ("soak-warmgpe", 2)):
+        names = await warm(base, width, 1000)
+        delete_pods(names)
+        async with asyncio.timeout(converge_timeout_s):
+            while any(sched.statedb.is_accounted(f"default/{nm}")
+                      for nm in names):
+                await asyncio.sleep(0.02)
+    warm_names: list[str] = []
+    warm_units = [("soak-warm0", 1, 0), ("soak-warmp", 1, 1000),
+                  ("soak-warmg", 2, 0), ("soak-warmgp", 2, 1000)]
+    for base, width, prio in warm_units:
+        warm_names += await warm(base, width, prio)
+    delete_pods(warm_names)
     for run_once in (autoscaler.run_once, desched.run_once):
         try:
             run_once()

@@ -21,6 +21,10 @@ Assume/forget semantics (cache.go:109 AssumePod, scheduler.go:224 rollback):
 the driver accounts an assignment optimistically via either path; a failed
 bind calls `remove_pod` which both fixes host numpy and marks the ledger
 dirty, forcing re-upload of the corrected truth.
+
+Every whole-array upload goes through `put_owned`: the host arrays are the
+live ledger the informer handlers mutate in place, and `jax.device_put`
+returns before it has read its source.
 """
 
 from __future__ import annotations
@@ -45,6 +49,29 @@ from kubernetes_tpu.state.cluster_state import (
     pod_requests,
 )
 from kubernetes_tpu.state.layout import Capacities
+from kubernetes_tpu.obs.tracing import stage
+
+# carried-term and attach-atom attribute columns ([UE] / [UA], replicated):
+# a membership refresh re-uploads them together
+_ATTR_FIELDS = ("attach_type", "term_q", "term_tkey", "term_weight",
+                "term_kind", "term_poison")
+
+
+def put_owned(tree, sharding=None):
+    """`jax.device_put` of a private host copy of every leaf of `tree`.
+
+    device_put returns before it has read a numpy source (the TPU reads it
+    later; the CPU backend may alias it outright), while StateDB's host
+    arrays go on changing under the informer handlers once a flush
+    releases the state lock. The copy, taken while the caller still holds
+    that lock, is the snapshot the device view keeps."""
+    host = jax.tree.map(lambda a: np.array(a, copy=True), tree)
+    return jax.device_put(host, sharding)
+
+
+def _nbytes(tree) -> int:
+    return sum(int(np.asarray(leaf).nbytes)
+               for leaf in jax.tree_util.tree_leaves(tree))
 
 
 @dataclass
@@ -341,7 +368,9 @@ class StateDB:
             rows is not None and 0 < len(rows)
             and len(rows) * 4 <= self.caps.num_nodes)
         if self._device is None or self._dirty_nodes:
-            dev = self._put(self.host)
+            with stage("ktpu.flush.upload_full", phase="upload_full",
+                       cpu=True):
+                dev = self._put(self.host)
             self.flush_full_total += 1
             self.flush_rows_total += self.caps.num_nodes
             self.flush_transfers_total += 1
@@ -349,43 +378,15 @@ class StateDB:
             self._m_transfers.inc()
         elif ledger_work or dirty_membership:
             dev = self._device
-            if can_scatter and ledger_work:
+            scattered = can_scatter and ledger_work
+            if scattered:
                 dev = self._scatter_rows(dev, rows)
-            elif self._dirty_ledger:
-                self.flush_full_total += 1
-                self.flush_rows_total += self.caps.num_nodes
-                self._m_rows.inc(self.caps.num_nodes)
-                dev = dev.replace(
-                    requested=self._put_arr(self.host.requested),
-                    nonzero_requested=self._put_arr(self.host.nonzero_requested),
-                    port_count=self._put_arr(self.host.port_count),
-                )
-                if self.table.vol_atoms:
-                    dev = dev.replace(
-                        vol_any=self._put_arr(self.host.vol_any),
-                        vol_rw=self._put_arr(self.host.vol_rw))
-                if self.table.attach_atoms:
-                    dev = dev.replace(
-                        attach_count=self._put_arr(self.host.attach_count))
-            if not (can_scatter and ledger_work) and \
-                    (self._dirty_ledger or self._dirty_affinity) and \
-                    self.table.podsels:
-                dev = dev.replace(
-                    podsel_count=self._put_arr(self.host.podsel_count),
-                    term_count=self._put_arr(self.host.term_count))
-            if dirty_membership:
-                dev = dev.replace(
-                    sel_member=self._put_arr(self.host.sel_member),
-                    req_member=self._put_arr(self.host.req_member),
-                    topology=self._put_arr(self.host.topology),
-                    volsel_member=self._put_arr(self.host.volsel_member),
-                    attach_type=jax.device_put(np.asarray(self.host.attach_type)),
-                    term_q=jax.device_put(np.asarray(self.host.term_q)),
-                    term_tkey=jax.device_put(np.asarray(self.host.term_tkey)),
-                    term_weight=jax.device_put(np.asarray(self.host.term_weight)),
-                    term_kind=jax.device_put(np.asarray(self.host.term_kind)),
-                    term_poison=jax.device_put(np.asarray(self.host.term_poison)),
-                )
+            whole_ledger = ledger_work and not scattered
+            if whole_ledger or dirty_membership:
+                with stage("ktpu.flush.upload_full", phase="upload_full",
+                           cpu=True):
+                    dev = self._put_whole_arrays(dev, whole_ledger,
+                                                 dirty_membership)
         else:
             return self._device
         self._device = dev
@@ -538,24 +539,65 @@ class StateDB:
         self.flush_bytes_total += nbytes
         self._m_bytes.inc(nbytes)
 
+    def _put_whole_arrays(self, dev: ClusterState, ledger: bool,
+                          membership: bool) -> ClusterState:
+        """Re-upload whole [N, W] arrays: the dirty ledger groups and
+        affinity counts when `ledger` (the row scatter did not take them),
+        and the membership columns with the carried-term and attach-atom
+        attributes when `membership`."""
+        if ledger and self._dirty_ledger:
+            self.flush_full_total += 1
+            self.flush_rows_total += self.caps.num_nodes
+            self._m_rows.inc(self.caps.num_nodes)
+            dev = dev.replace(
+                requested=self._put_arr(self.host.requested),
+                nonzero_requested=self._put_arr(self.host.nonzero_requested),
+                port_count=self._put_arr(self.host.port_count),
+            )
+            if self.table.vol_atoms:
+                dev = dev.replace(
+                    vol_any=self._put_arr(self.host.vol_any),
+                    vol_rw=self._put_arr(self.host.vol_rw))
+            if self.table.attach_atoms:
+                dev = dev.replace(
+                    attach_count=self._put_arr(self.host.attach_count))
+        if ledger and self.table.podsels:
+            dev = dev.replace(
+                podsel_count=self._put_arr(self.host.podsel_count),
+                term_count=self._put_arr(self.host.term_count))
+        if membership:
+            self.flush_transfers_total += 1
+            self._m_transfers.inc()
+            dev = dev.replace(
+                sel_member=self._put_arr(self.host.sel_member),
+                req_member=self._put_arr(self.host.req_member),
+                topology=self._put_arr(self.host.topology),
+                volsel_member=self._put_arr(self.host.volsel_member),
+                **self._upload({f: getattr(self.host, f)
+                                for f in _ATTR_FIELDS}))
+        return dev
+
+    def _upload(self, tree, sharding=None):
+        """One host->device transfer of an owned copy of `tree`, its bytes
+        counted."""
+        self._count_flush_bytes(_nbytes(tree))
+        return put_owned(tree, sharding)
+
     def _put(self, state: ClusterState) -> ClusterState:
-        host = jax.tree.map(np.asarray, state)
-        self._count_flush_bytes(sum(
-            int(leaf.nbytes) for leaf in jax.tree_util.tree_leaves(host)))
         if self.mesh is not None:
             from kubernetes_tpu.parallel.mesh import shard_state
+            self._count_flush_bytes(_nbytes(state))
             return shard_state(state, self.mesh)
         # ONE batched transfer for the whole pytree — per-leaf puts pay a
         # per-call latency each
-        return jax.device_put(host)
+        return self._upload(state)
 
     def _put_arr(self, arr: np.ndarray):
         self.flush_transfers_total += 1
         self._m_transfers.inc()
-        self._count_flush_bytes(int(np.asarray(arr).nbytes))
+        sharding = None
         if self.mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec
             from kubernetes_tpu.parallel.mesh import NODE_AXIS
-            return jax.device_put(
-                np.asarray(arr), NamedSharding(self.mesh, PartitionSpec(NODE_AXIS)))
-        return jax.device_put(np.asarray(arr))
+            sharding = NamedSharding(self.mesh, PartitionSpec(NODE_AXIS))
+        return self._upload(arr, sharding)

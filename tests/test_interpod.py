@@ -295,3 +295,92 @@ def test_solver_serial_parity_interpod(seed):
     caps = Capacities(num_nodes=8, batch_pods=16)
     got = solve(nodes, pods, assigned=assigned, policy=FULL_POLICY, caps=caps)
     assert got == expected
+
+
+# ---- the k8s-5k zone-affinity deployment's mechanism ------------------
+
+S1, S2 = {"security": "S1"}, {"security": "S2"}
+# the Kubernetes docs' pods/pod-with-pod-affinity.yaml terms
+EXAMPLE_AFF = aff(
+    required=[term(S1)],
+    anti_preferred=[{"weight": 100, "podAffinityTerm": term(S2)}])
+
+
+def _example_pod(name, role, node=None):
+    if role == "example":
+        return mk_pod(name, {"app": "with-pod-affinity"},
+                      affinity=EXAMPLE_AFF, node=node)
+    return mk_pod(name, S1 if role == "s1" else S2, node=node)
+
+
+def _zone_affinity_cluster(rng, per_zone=2):
+    """3 zones of `per_zone` nodes; each zone holds 257-264 residents of
+    each role (past the 256 to which a bf16 count operand holds integers),
+    on random nodes of the zone."""
+    nodes = [Node.from_dict({
+        "metadata": {"name": f"z{z}n{i}", "labels": {ZONE: f"z{z}",
+                                                     HOST: f"z{z}n{i}"}},
+        "status": {"allocatable": {"cpu": "64", "memory": "256Gi",
+                                   "pods": "2000"},
+                   "conditions": [{"type": "Ready", "status": "True"}]}})
+        for z in range(3) for i in range(per_zone)]
+    residents = []
+    for z in range(3):
+        counts = {role: 257 + rng.randint(8)
+                  for role in ("s1", "s2", "example")}
+        for role, n in counts.items():
+            for _ in range(n):
+                node = f"z{z}n{rng.randint(per_zone)}"
+                residents.append(_example_pod(f"r{len(residents)}", role,
+                                              node=node))
+    return nodes, residents
+
+
+# seeds on which rounding the zone counts to bf16 moves a decision
+@pytest.mark.parametrize("seed", [0, 1, 3])
+def test_zone_affinity_deployment_parity_under_deletes(seed):
+    """The k8s-5k / zone-affinity-saturated mechanism at small size:
+    StateDB's flushes (whole-array re-uploads of the ledger and affinity
+    counts when deletes dirty most rows) feeding the solver, batch after
+    batch with resident deletes in between, against the serial scheduler
+    on the same pods. Every decision must be the serial scheduler's
+    exactly: k8s-5k.json states integer scores held exactly, and a
+    zone's counts of each role here (257-264) are past the 256 to which a bf16
+    count operand holds integers, so any rounding of them shows."""
+    from kubernetes_tpu.state import encode_pods
+    from kubernetes_tpu.state.statedb import StateDB
+
+    rng = np.random.RandomState(seed + 2800)
+    nodes, residents = _zone_affinity_cluster(rng)
+    caps = Capacities(num_nodes=8, batch_pods=8)
+    db = StateDB(caps)
+    for n in nodes:
+        db.upsert_node(n)
+    for p in residents:
+        assert db.add_pod(p)
+    db.flush()
+    rr = 0
+    roles = ("s1", "s2", "example")
+    for rnd in range(6):
+        # the informer's deletes since the last flush
+        for _ in range(int(rng.randint(4, 24))):
+            gone = residents.pop(rng.randint(len(residents)))
+            db.remove_pod(gone.key)
+        pods = [_example_pod(f"b{rnd}-{i}", roles[rng.randint(3)])
+                for i in range(caps.batch_pods)]
+        ref = SerialScheduler(nodes, residents, with_interpod=True)
+        ref.rr = rr
+        expected = ref.schedule(pods)
+        batch = encode_pods(pods, caps, db.table)
+        state = db.flush()
+        result = jit_schedule(state, batch, rr, FULL_POLICY, caps)
+        got = [db.table.name_of[int(i)] if int(i) >= 0 else None
+               for i in result.assignments[:len(pods)]]
+        assert got == expected, f"round {rnd}"
+        rr = int(result.rr_end)
+        assert rr == ref.rr
+        for pod, node in zip(pods, got):
+            if node is not None:
+                pod.spec.node_name = node
+                db.add_pod(pod)
+                residents.append(pod)

@@ -307,3 +307,46 @@ def test_phase_clocks_unchanged(slept_run, phase):
         assert got <= busy["commit"] - 0.9 * slept["commit"]
     else:
         assert got >= slept["install"]
+
+
+class _Phases:
+    def __init__(self):
+        self.phase_s = {}
+
+    def add_phase(self, name, seconds):
+        self.phase_s[name] = self.phase_s.get(name, 0.0) + seconds
+
+
+def test_stage_without_metrics_reports_to_the_enclosing_stage():
+    """A stage given no metrics (StateDB's `ktpu.flush.upload_full`)
+    adds its phase to the innermost enclosing stage's metrics, records
+    nothing outside one, and an asyncio task does not see a stage that
+    another task holds open across an await."""
+    outer, other = _Phases(), _Phases()
+    with tracing.stage("outer", metrics=outer, phase="flush"):
+        with tracing.stage("inner", phase="upload_full"):
+            pass
+    assert set(outer.phase_s) == {"flush", "upload_full"}
+    with tracing.stage("alone", phase="upload_full"):
+        pass                                   # no enclosing stage
+    assert tracing._STAGE_METRICS.get() is None
+
+    async def holder(opened, release):
+        with tracing.stage("held", metrics=other, phase="held"):
+            opened.set()
+            await release.wait()
+
+    async def bystander(opened):
+        await opened.wait()
+        with tracing.stage("bystander", phase="bystander"):
+            pass
+
+    async def run():
+        opened, release = asyncio.Event(), asyncio.Event()
+        task = asyncio.create_task(holder(opened, release))
+        await bystander(opened)
+        release.set()
+        await task
+
+    asyncio.run(run())
+    assert set(other.phase_s) == {"held"}
