@@ -22,6 +22,7 @@ import asyncio
 import dataclasses
 import logging
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -158,6 +159,13 @@ class SchedulerMetrics:
         self._c_jit_misses = r.counter(
             "scheduler_jit_cache_misses_total",
             "Batches that compiled a new solver variant (BatchFlags).")
+        self._c_solve_batches = r.counter(
+            "scheduler_solve_batches_total",
+            "Solver batches by the row count they were solved at (the "
+            "rung of the batch ladder).", ("rows",))
+        self._c_solve_padding = r.counter(
+            "scheduler_solve_padding_rows_total",
+            "Padding rows solved: rung rows past each batch's pods.")
         self._c_gang_placed = r.counter(
             "scheduler_gang_groups_placed_total",
             "Gangs whose quorum placed and bound atomically.")
@@ -223,6 +231,9 @@ class SchedulerMetrics:
         self.solve_retries = 0
         self.quarantined = 0
         self.serial_fallback = 0
+        # batches per rung (rows -> count) and padding rows solved
+        self.solve_batches: dict[int, int] = {}
+        self.solve_padding_rows = 0
         # bounded windows (the registry histograms are cumulative; the
         # windows keep the recent-sample percentiles snapshot() reports)
         self.e2e_latency = _LatencyWindow(r.histogram(
@@ -290,6 +301,12 @@ class SchedulerMetrics:
 
     def jit_miss(self) -> None:
         self._c_jit_misses.inc()
+
+    def solve_rows_inc(self, rows: int, pods: int) -> None:
+        self.solve_batches[rows] = self.solve_batches.get(rows, 0) + 1
+        self.solve_padding_rows += rows - pods
+        self._c_solve_batches.labels(str(rows)).inc()
+        self._c_solve_padding.inc(rows - pods)
 
     def gang_placed_inc(self) -> None:
         self.gang_placed += 1
@@ -380,6 +397,10 @@ class SchedulerMetrics:
             out["phase_us_per_pod"] = {
                 k: round(1e6 * v / self.phase_pods, 2)
                 for k, v in sorted(self.phase_s.items())}
+        if self.solve_batches:
+            out["solve_rows"] = {
+                "batches": dict(sorted(self.solve_batches.items())),
+                "padding_rows": self.solve_padding_rows}
         if self.gang_placed or self.gang_reverted or self.gang_timeouts:
             out["gang"] = {"placed": self.gang_placed,
                            "reverted": self.gang_reverted,
@@ -431,6 +452,56 @@ def store_encode_context(store: ObjectStore, policy: Policy = DEFAULT_POLICY,
         service_affinity_labels=policy.service_affinity_labels(),
         service_anti=bool(policy.service_anti_priorities),
     )
+
+
+# JAX tracing on a worker thread keeps the GIL for whole switch
+# intervals at a time: with the default 5 ms, tracing one solver program
+# stalled the event loop for 0.2-1 s on the CPU. A variant's first call
+# traces every rung of the ladder, so it runs with a finer interval,
+# restored after. The lock keeps two warm-ups from interleaving the
+# save and the restore.
+_WARM_SWITCH_INTERVAL_S = 0.0005
+_WARM_LOCK = threading.Lock()
+
+
+class _LadderProgram:
+    """One solver variant (BatchFlags) at every rung of the batch ladder
+    (state/pod_batch.solve_rungs): a call runs the program compiled for
+    its blobs' row count. The first call with a victim table, and the
+    first without one, dispatch their own batch and then run every other
+    rung once on all-padding blobs, so the variant's later batches,
+    whatever their size, compile nothing. That first call happens on the
+    thread that dispatches the solve, never on the event loop."""
+
+    def __init__(self, programs: dict):
+        self.programs = programs      # rows -> instrumented jit program
+        self._warm: set[bool] = set()
+
+    def __call__(self, state, fblob, iblob, rr, victims=None):
+        shape = victims is None
+        rows = fblob.shape[0]
+        if shape in self._warm:
+            return self.programs[rows](state, fblob, iblob, rr, victims)
+        with _WARM_LOCK:
+            interval = sys.getswitchinterval()
+            sys.setswitchinterval(_WARM_SWITCH_INTERVAL_S)
+            try:
+                out = self.programs[rows](state, fblob, iblob, rr, victims)
+                if shape not in self._warm:
+                    for other, fn in self.programs.items():
+                        if other != rows:
+                            fn(state,
+                               np.zeros((other, fblob.shape[1]), np.float32),
+                               np.zeros((other, iblob.shape[1]), np.int32),
+                               rr, victims)
+                    self._warm.add(shape)
+            finally:
+                sys.setswitchinterval(interval)
+        return out
+
+    def lower(self, state, fblob, iblob, rr, victims=None):
+        return self.programs[fblob.shape[0]].lower(state, fblob, iblob, rr,
+                                                   victims)
 
 
 # back-compat alias (pre-spreading name)
@@ -632,7 +703,9 @@ class Scheduler:
 
     def _get_schedule_fn(self, flags):
         """Compiled solver variant for this batch's content gates — a
-        handful of variants in practice (jit caches per BatchFlags)."""
+        handful of variants in practice — at every rung of the batch
+        ladder: one program per (BatchFlags, rows), each registered as
+        `<variant>@p<rows>`."""
         import jax
 
         fn = self._schedule_fns.get(flags)
@@ -640,24 +713,42 @@ class Scheduler:
             self.metrics.jit_hit()
         else:
             self.metrics.jit_miss()
-            from kubernetes_tpu.state.pod_batch import unpack_batch
+            from kubernetes_tpu.state.pod_batch import (solve_rungs,
+                                                        unpack_batch)
 
             caps, policy, prows = self.caps, self.policy, self._prows
             if self.mesh is not None:
                 from kubernetes_tpu.parallel.mesh import make_sharded_scheduler
-                fn = make_sharded_scheduler(self.mesh, policy, caps=caps,
-                                            prows=prows, flags=flags,
-                                            packed=True)
+                jfn = make_sharded_scheduler(self.mesh, policy, caps=caps,
+                                             prows=prows, flags=flags,
+                                             packed=True)
             else:
-                fn = jax.jit(
+                jfn = jax.jit(
                     lambda s, fb, ib, rr, v=None: schedule_batch(
                         s, unpack_batch(fb, ib, caps), rr, policy,
                         caps=caps, prows=prows, flags=flags, victims=v))
             # compile registry (obs/profiling.py): first-call compile
-            # seconds + cost_analysis per variant ride the cache entry
-            fn = COMPILES.instrument(self._variant_key(flags), fn)
+            # seconds + cost_analysis per rung ride the cache entry
+            key = self._variant_key(flags)
+            fn = _LadderProgram({
+                rows: COMPILES.instrument(f"{key}@p{rows}", jfn)
+                for rows in solve_rungs(caps.batch_pods)})
             self._schedule_fns[flags] = fn
         return fn
+
+    def _solve_blobs(self, fblob, iblob, n: int):
+        """The rows of a batch's pooled blob pair that its solve reads:
+        the smallest rung of the ladder that holds its n pods. The rows
+        past the pods are zeroed (a reused blob still holds the previous
+        batch's encodings, valid flags in particular); the views are
+        contiguous, no copy."""
+        from kubernetes_tpu.state.pod_batch import solve_rows
+
+        rows = solve_rows(n, self.caps.batch_pods)
+        fblob[n:rows] = 0.0
+        iblob[n:rows] = 0
+        self.metrics.solve_rows_inc(rows, n)
+        return fblob[:rows], iblob[:rows]
 
     def _on_workload_event(self, event: WatchEvent) -> None:
         self.encode_cache.generation += 1
@@ -1132,11 +1223,12 @@ class Scheduler:
                     np.zeros((p, i_width), np.int32))
 
     def _release_blobs(self, blobs) -> None:
-        """Return a blob pair once its batch's ledger commit has read the
-        accounting rows (callable from the commit stage thread — deque
-        append is atomic)."""
+        """Return a blob pair, or its row views, once its batch's ledger
+        commit has read the accounting rows (callable from the commit
+        stage thread — deque append is atomic)."""
         if len(self._blob_pool) < self.pipeline_depth + 2:
-            self._blob_pool.append(blobs)
+            self._blob_pool.append(tuple(
+                b if b.base is None else b.base for b in blobs))
 
     def _next_blobs(self):
         """Back-compat acquire-without-release (tests' scratch blobs):
@@ -1234,13 +1326,6 @@ class Scheduler:
                 for i, pod in enumerate(pods):
                     self.encode_cache.encode_packed_into(fblob, iblob, i,
                                                          pod)
-        if not pods:
-            return fblob, iblob, pods, live_keys, gang_groups
-        # unused tail rows of a reused blob must not leak the previous
-        # batch's encodings (valid flags in particular)
-        if len(pods) < self.caps.batch_pods:
-            fblob[len(pods):] = 0.0
-            iblob[len(pods):] = 0
         if gang_groups:
             # gang columns go in AFTER encoding: cached packed rows carry
             # zeros (a batch-local group id cannot be cached), and the
@@ -1284,6 +1369,7 @@ class Scheduler:
                                                            fblob, iblob)
             finally:
                 self._release_blobs((fblob, iblob))
+        fblob, iblob = self._solve_blobs(fblob, iblob, len(pods))
         if self._staged is not None and not self._stopped:
             return await self._schedule_batch_staged(
                 pods, live_keys, fblob, iblob, gang_groups, batch_span, seq)

@@ -71,6 +71,7 @@ from kubernetes_tpu.state.pod_batch import (
     _layout,
     blob_col,
     packed_batch_flags,
+    solve_rows,
     unpack_batch,
 )
 from kubernetes_tpu.state.statedb import StateDB
@@ -535,8 +536,7 @@ class SolverService:
     # ---- shape buckets & programs ----
 
     def _bucket(self, n: int) -> int:
-        b = max(self.min_bucket, 1 << max(0, int(n) - 1).bit_length())
-        return min(b, self.caps.batch_pods)
+        return solve_rows(n, self.caps.batch_pods, self.min_bucket)
 
     def _eval_fn(self, bucket: int):
         fn = self._eval_fns.get(bucket)

@@ -288,6 +288,25 @@ def packed_batch_flags(fblob, iblob, n: int, table, caps: Capacities):
     )
 
 
+def solve_rows(n: int, top: int, floor: int | None = None) -> int:
+    """Rows a padded solve of n pods runs at: the smallest power of two
+    that holds n, at least `floor`, at most `top`. `floor` defaults to
+    top // 8, the scheduler's batch ladder (512, 1,024, 2,048, 4,096 at
+    4,096 pods a batch); solversvc passes its own minimum bucket."""
+    if floor is None:
+        floor = top // 8
+    return min(top, max(floor, 1 << max(0, int(n) - 1).bit_length()))
+
+
+def solve_rungs(top: int) -> tuple[int, ...]:
+    """Every row count solve_rows(n, top) gives, ascending: the rungs of
+    the scheduler's batch ladder."""
+    rungs = [solve_rows(1, top)]
+    while rungs[-1] < top:
+        rungs.append(solve_rows(rungs[-1] + 1, top))
+    return tuple(rungs)
+
+
 def unpack_batch(fblob, iblob, caps: Capacities) -> PodBatch:
     """Device-side (jit-traceable): rebuild the PodBatch pytree by slicing
     the blobs — pure views for XLA, no data movement."""
